@@ -3,6 +3,7 @@ real performance comes from the roofline analysis of the compiled dry-run)."""
 from __future__ import annotations
 
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,8 @@ def run():
         q = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
         k = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
         v = jnp.asarray(rng.standard_normal((B, H, S, D)), jnp.float32)
-        t_kern = _t(lambda a, b, c: ops.flash_attention(a, b, c, causal=True),
+        t_kern = _t(lambda a, b, c: ops.flash_attention(a, b, c, causal=True,
+                                                         interpret=True),
                     q, k, v)
         t_ref = _t(lambda a, b, c: ref.attention_reference(a, b, c,
                                                            causal=True),
@@ -44,15 +46,16 @@ def run():
         bm = jnp.asarray(rng.standard_normal((B, S, N)) * .3, jnp.float32)
         cm = jnp.asarray(rng.standard_normal((B, S, N)) * .3, jnp.float32)
         rows.append({"kernel": "ssd_scan", "shape": f"{B}x{H}x{S}x{P}x{N}",
-                     "interpret_ms": round(_t(ops.ssd_scan, xdt, a, bm,
-                                              cm) * 1e3, 2),
+                     "interpret_ms": round(_t(partial(ops.ssd_scan, interpret=True),
+                                              xdt, a, bm, cm) * 1e3, 2),
                      "ref_ms": round(_t(ref.ssd_reference, xdt, a, bm,
                                         cm) * 1e3, 2)})
 
         src = jnp.asarray(rng.standard_normal((256, 64, 128)), jnp.float32)
         idx = jnp.asarray(rng.permutation(256), jnp.int32)
         rows.append({"kernel": "blockcyclic_repack", "shape": "256x64x128",
-                     "interpret_ms": round(_t(ops.repack, src, idx) * 1e3, 2),
+                     "interpret_ms": round(_t(partial(ops.repack, interpret=True),
+                                              src, idx) * 1e3, 2),
                      "ref_ms": round(_t(ref.repack_reference, src,
                                         idx) * 1e3, 2)})
     path = write_csv("kernel_microbench", rows)

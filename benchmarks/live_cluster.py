@@ -36,24 +36,11 @@ import dataclasses
 import json
 import os
 import resource
-import subprocess
-import sys
 import time
 
-from benchmarks.common import report, timer, write_csv
+from benchmarks.common import (force_host_devices, report, require_devices,
+                               timer, write_csv)
 
-
-def _ensure_device_farm():
-    """Standalone entry only (main): force an 8-device host farm before
-    jax initializes.  Never at import time — benchmarks.run imports this
-    module alongside every other benchmark, and mutating XLA_FLAGS there
-    would silently change *their* device topology; in that path run()
-    detects the undersized backend and replays in a child instead."""
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            " --xla_force_host_platform_device_count=8").strip()
 
 POLICY_NAMES = ("algorithm2", "throughput-greedy")
 MODES = ("rigid", "moldable")
@@ -63,8 +50,7 @@ BENCH_JSON = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def _devices():
-    import jax
-    return jax.devices()[:8]
+    return require_devices(8, "live_cluster")
 
 
 def _row(policy, mode, s, base):
@@ -146,26 +132,7 @@ def _crosscheck(n_jobs, max_steps, seed):
 
 
 def run(n_jobs=10, max_steps=16, seed=0):
-    import jax
-    if len(jax.devices()) < 8:
-        # the interpreter's backend was initialized before our XLA_FLAGS
-        # could take effect (benchmarks.run imports every module up
-        # front): replay in a child with its own 8-device farm
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8",
-                   PYTHONPATH="src", PYTHONWARNINGS="ignore")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.live_cluster",
-             "--jobs", str(n_jobs), "--steps", str(max_steps),
-             "--seed", str(seed)],
-            env=env, capture_output=True, text=True, timeout=560)
-        lines = [l for l in out.stdout.splitlines()
-                 if l.startswith("live_cluster,")]
-        if out.returncode != 0 or not lines:
-            raise RuntimeError(f"child live_cluster run failed:\n"
-                               f"{out.stdout[-2000:]}\n{out.stderr[-2000:]}")
-        print(lines[0])
-        return None
+    _devices()                          # fail fast, before any work
     with timer() as t:
         rows, per_job = _grid(n_jobs, max_steps, seed)
         xc = _crosscheck(n_jobs, max_steps, seed)
@@ -358,7 +325,7 @@ def main():
                        seed=args.seed, trace=args.trace,
                        trail_path=args.trail_out)
         return
-    _ensure_device_farm()
+    force_host_devices(8)
     n_jobs = args.jobs or (6 if args.smoke else 10)
     max_steps = args.steps or (10 if args.smoke else 16)
     run(n_jobs=n_jobs, max_steps=max_steps, seed=args.seed)

@@ -35,11 +35,10 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 
-from benchmarks.common import report, write_csv
+from benchmarks.common import (force_host_devices, report, require_devices,
+                               write_csv)
 
 BENCH_JSON = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "BENCH_serving.json")
@@ -62,8 +61,7 @@ def _serve_config(max_replicas: int):
 # part 1 — live scale-up latency
 # ----------------------------------------------------------------------
 
-def _scale_latency(n_trials: int = 3) -> dict:
-    import jax
+def _scale_latency(devices, n_trials: int = 3) -> dict:
     from repro.configs import get_config
     from repro.core.params import MalleabilityParams
     from repro.core.policy import Action
@@ -84,11 +82,11 @@ def _scale_latency(n_trials: int = 3) -> dict:
 
     # throwaway build: absorb the one-time jax/backend warmup so the
     # cold-start samples measure replica bring-up, not process init
-    cold_start(jax.devices()[:2])
+    cold_start(devices[:2])
 
     # a malleable replica holding grow headroom: mesh at 2 of 4 devices
     r = MalleableRunner(factory(), MalleabilityParams(2, 4, 2),
-                        devices=jax.devices()[:4], initial_procs=2,
+                        devices=devices[:4], initial_procs=2,
                         allow_partial=True)
     state = r.init()
     r.prewarm()
@@ -110,7 +108,7 @@ def _scale_latency(n_trials: int = 3) -> dict:
         grows.append(dt)
     in_place_s = sum(grows) / len(grows)
 
-    colds = [cold_start(jax.devices()[2 * (1 + k):2 * (2 + k)])
+    colds = [cold_start(devices[2 * (1 + k):2 * (2 + k)])
              for k in range(n_trials)]
     cold_s = sum(colds) / len(colds)
 
@@ -216,35 +214,12 @@ def _pool_grid(p, seed):
 
 
 def run(smoke: bool = False, seed: int = SEED, trail_path=None):
-    import jax
-    if len(jax.devices()) < 8:
-        # backend initialized before an 8-device farm could be forced
-        # (benchmarks.run imports every module up front): replay in a
-        # child with its own farm — same pattern as live_cluster
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8",
-                   PYTHONPATH="src", PYTHONWARNINGS="ignore")
-        cmd = [sys.executable, "-m", "benchmarks.mixed_pool",
-               "--seed", str(seed)]
-        if smoke:
-            cmd.append("--smoke")
-        if trail_path:
-            cmd += ["--trail-out", trail_path]
-        out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                             timeout=560)
-        lines = [l for l in out.stdout.splitlines()
-                 if l.startswith("mixed_pool,")]
-        if out.returncode != 0 or not lines:
-            raise RuntimeError(f"child mixed_pool run failed:\n"
-                               f"{out.stdout[-2000:]}\n{out.stderr[-2000:]}")
-        print(lines[0])
-        return None
-
+    devices = require_devices(8, "mixed_pool")
     from repro.analysis.trail import dump_trail
 
     t_start = time.perf_counter()
     p = dict(SMOKE if smoke else FULL)
-    latency = _scale_latency()
+    latency = _scale_latency(devices)
     rows, ticks_to_capacity, shared = _pool_grid(p, seed)
     if trail_path:
         dump_trail(shared, trail_path)
@@ -285,11 +260,7 @@ def main():
                     help="dump the shared cluster's trail JSON here "
                          "(analysis-job audit artifact)")
     args = ap.parse_args()
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") +
-            " --xla_force_host_platform_device_count=8").strip()
+    force_host_devices(8)
     print("name,us_per_call,derived")
     run(smoke=args.smoke, seed=args.seed, trail_path=args.trail_out)
 

@@ -17,7 +17,7 @@ import numpy as np
 from benchmarks.common import report, timer, write_csv
 from repro.configs import SHAPES_BY_NAME, all_configs
 from repro.core.params import MalleabilityParams
-from repro.launch.roofline import PEAK_FLOPS, model_flops
+from repro.launch.roofline import chip_peaks, model_flops
 from repro.rms import SimConfig, Simulator
 from repro.rms.workload import AppProfile, Job, feitelson_arrivals
 
@@ -25,6 +25,7 @@ CHIPS = 512
 SLICE = 64
 STEPS = 500                     # pretraining segment per job
 FALLBACK_MFU = 0.30
+PEAK_FLOPS = chip_peaks("TPU v5 lite").flops
 
 
 def _anchored_mfu(arch: str) -> float:

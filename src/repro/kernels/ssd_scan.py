@@ -27,30 +27,38 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)  — already x*dt
-    a = a_ref[0, 0].astype(jnp.float32)          # (Q,)    — dt * A (negative)
+    a = a_ref[0, 0].astype(jnp.float32)          # (1, Q)  — dt * A (negative)
     bm = b_ref[0].astype(jnp.float32)            # (Q, N)
     cm = c_ref[0].astype(jnp.float32)            # (Q, N)
 
-    cum = jnp.cumsum(a)                          # (Q,)
+    # cumulative decay as a column and as a row, both from the lane-major
+    # ``a``: a masked lane reduction and a matmul with the upper triangle
+    # (Pallas has no TPU lowering for cumsum)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = row >= col
+    a_b = jnp.broadcast_to(a, (chunk, chunk))    # a_b[i, k] = a[k]
+    cum_c = jnp.sum(jnp.where(causal, a_b, 0.0), axis=1,
+                    keepdims=True)               # (Q, 1): cum[i]
+    cum_r = jax.lax.dot_general(
+        a_b[:8], jnp.where(row <= col, 1.0, 0.0),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)[:1]  # (1, Q): cum[j]
     # within-chunk duality term
     g = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, Q)
-    diff = cum[:, None] - cum[None, :]
-    causal = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    l = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    l = jnp.exp(jnp.where(causal, cum_c - cum_r, -jnp.inf))
     y = jax.lax.dot_general(g * l, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, P)
     # incoming-state term: y_off[q] = exp(cum[q]) * C[q] @ state^T
     state = state_ref[...]                       # (P, N)
     y_off = jax.lax.dot_general(cm, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y = y + y_off * jnp.exp(cum)[:, None]
+    y = y + y_off * jnp.exp(cum_c)
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update: state' = state * exp(total) + sum_q decay_q * x[q] (x) B[q]
-    total = cum[-1]
-    decay = jnp.exp(total - cum)                 # (Q,)
-    xw = x * decay[:, None]                      # (Q, P)
+    total = jnp.sum(a, axis=1, keepdims=True)    # (1, 1)
+    xw = x * jnp.exp(total - cum_c)              # (Q, P)
     state_ref[...] = state * jnp.exp(total) + jax.lax.dot_general(
         xw, bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)      # (P, N)
@@ -72,12 +80,15 @@ def ssd_scan_fwd(xdt, a, bm, cm, *, chunk: int = 256,
     nc = S // Q
 
     kernel = functools.partial(_ssd_kernel, chunk=Q)
+    # ``a`` rides lane-major, (B, H, 1, S): a (1, Q) block of it meets the
+    # chip's tiling rule, where a (1, 1, Q) block of (B, H, S) does not
+    a = a.reshape(B, H, 1, S)
     return pl.pallas_call(
         kernel,
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, Q), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),   # h-shared
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),   # h-shared
         ],

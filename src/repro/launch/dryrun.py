@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax import (jax locks the device
-count on first init); 512 host devices back both the single-pod (16, 16) and
-multi-pod (2, 16, 16) production meshes.
+The lines above MUST run before any jax import (jax locks the device count
+on first init); 512 host devices back both the single-pod (16, 16) and
+multi-pod (2, 16, 16) production meshes. This is a CPU-only tool: importing
+it rewrites the process's XLA flags and platform, so nothing on the chip
+path (``launch/train.py``, ``launch/serve.py``, ``chip_smoke.py``) imports it.
 
 Per cell this prints/records:
   * compiled.memory_analysis()  — proves the cell fits per-chip HBM
@@ -33,7 +36,7 @@ from repro.configs import (SHAPES, all_configs, get_config, get_shape,
 from repro.data.pipeline import input_specs                    # noqa: E402
 from repro.launch.hloanalysis import analyze_hlo               # noqa: E402
 from repro.launch.mesh import make_production_mesh             # noqa: E402
-from repro.launch.roofline import CHIP_HBM_BYTES, build_roofline  # noqa: E402
+from repro.launch.roofline import build_roofline, chip_peaks  # noqa: E402
 from repro.models import model as M                            # noqa: E402
 from repro.models.train import (abstract_state, make_prefill_step,
                                 make_serve_step, make_train_step)  # noqa: E402
@@ -42,6 +45,10 @@ from repro.parallel.context import sharding_context            # noqa: E402
 from repro.parallel.sharding import (batch_shardings, cache_shardings,
                                      param_shardings, replicated, rules_for,
                                      state_shardings)          # noqa: E402
+
+
+#: the chip the production meshes model (their devices are host devices)
+PEAKS = chip_peaks("TPU v5 lite")
 
 
 def lower_cell(arch: str, shape_name: str, multi_pod: bool,
@@ -124,7 +131,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir=None,
     ca = ca[0] if isinstance(ca, list) else ca
     mem = float(ma.argument_size_in_bytes + ma.temp_size_in_bytes)
     hlo = analyze_hlo(compiled.as_text(), chips)
-    rl = build_roofline(cfg, shape, mesh_name, chips, hlo, mem)
+    rl = build_roofline(cfg, shape, mesh_name, chips, hlo, mem, PEAKS)
     rec = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name,
         "status": "ok", "chips": chips,
@@ -134,7 +141,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir=None,
             "temp_gib": ma.temp_size_in_bytes / 2 ** 30,
             "output_gib": ma.output_size_in_bytes / 2 ** 30,
             "total_gib": mem / 2 ** 30,
-            "fits_16gib": mem <= CHIP_HBM_BYTES,
+            "fits_16gib": mem <= PEAKS.hbm_bytes,
         },
         "xla_cost_analysis": {"flops": ca.get("flops", 0.0),
                               "bytes_accessed": ca.get("bytes accessed", 0.0)},

@@ -1,8 +1,8 @@
-"""Roofline terms for TPU v5e from the dry-run's compiled artifact.
+"""Roofline terms from the dry-run's compiled artifact.
 
-    compute term    = FLOPs / (chips x 197e12)
-    memory term     = HBM bytes / (chips x 819e9)
-    collective term = wire bytes / (chips x 50e9)
+    compute term    = FLOPs / peak FLOP/s
+    memory term     = HBM bytes / peak HBM bytes/s
+    collective term = wire bytes / ICI bytes/s per link
 
 FLOPs / bytes / collective bytes come from the trip-count-aware HLO analysis
 (repro.launch.hloanalysis) of the SPMD-partitioned module: per-device values,
@@ -18,10 +18,33 @@ from typing import Dict, Optional
 from repro.configs.base import ArchConfig, ShapeConfig, phys_vocab
 from repro.launch.hloanalysis import HLOAnalysis
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per v5e chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link (assignment constant)
-CHIP_HBM_BYTES = 16 * 2 ** 30
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float               # bf16 FLOP/s
+    hbm_bw: float              # HBM bytes/s
+    ici_link_bw: float         # interchip bytes/s per link
+    hbm_bytes: int
+
+
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+#: 819 GB/s, 1,600 Gbit/s of interchip interconnect (50 GB/s on each of a
+#: chip's four links).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_link_bw=50e9,
+                             hbm_bytes=16 * 2 ** 30),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 # ----------------------------------------------------------------------
@@ -143,18 +166,18 @@ class Roofline:
 
 
 def build_roofline(cfg: ArchConfig, shape: ShapeConfig, mesh_name: str,
-                   chips: int, hlo: HLOAnalysis,
-                   memory_bytes: float, note: str = "") -> Roofline:
-    compute_s = hlo.dot_flops / PEAK_FLOPS
-    memory_s = hlo.hbm_bytes / HBM_BW
-    collective_s = hlo.collective_wire_bytes / ICI_BW
+                   chips: int, hlo: HLOAnalysis, memory_bytes: float,
+                   peaks: ChipPeaks, note: str = "") -> Roofline:
+    compute_s = hlo.dot_flops / peaks.flops
+    memory_s = hlo.hbm_bytes / peaks.hbm_bw
+    collective_s = hlo.collective_wire_bytes / peaks.ici_link_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     mf = model_flops(cfg, shape)
     hlo_global = hlo.dot_flops * chips
     step = max(terms.values())
-    mfu = mf / (chips * PEAK_FLOPS * step) if step > 0 else 0.0
+    mfu = mf / (chips * peaks.flops * step) if step > 0 else 0.0
     return Roofline(
         arch=cfg.name, shape=shape.name, mesh=mesh_name, chips=chips,
         compute_s=compute_s, memory_s=memory_s, collective_s=collective_s,

@@ -9,37 +9,27 @@ redistribution-pattern registry, with bit-identical tokens before and
 after.  The heavy lifting lives in :func:`repro.serve.decode_demo`;
 this module only parses flags and prints.
 
-  python -m repro.launch.serve --arch mamba2-370m-smoke --batch 4 \\
-      --prompt-len 32 --decode-steps 16 --host-devices 8 \\
+  JAX_PLATFORMS=cpu python -m repro.launch.serve --arch mamba2-370m-smoke \\
+      --batch 4 --prompt-len 32 --decode-steps 16 --host-devices 8 \\
       --resize-at 40 --resize-to 8
+
+The replica starts on one device and may grow to the largest power of two
+of the devices JAX finds.
 """
 import argparse
 import os
-import sys
+from typing import Optional, Sequence
 
 
-def _early_devices():
-    for i, a in enumerate(sys.argv):
-        if a == "--host-devices":
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count={int(sys.argv[i+1])}")
-
-
-_early_devices()
-
-import warnings                                   # noqa: E402
-warnings.filterwarnings("ignore")
-
-
-def main():
+def main(argv: Optional[Sequence[str]] = None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", required=True)
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--decode-steps", type=int, default=16)
     p.add_argument("--cache-len", type=int, default=128)
-    p.add_argument("--host-devices", type=int, default=None)
+    p.add_argument("--host-devices", type=int, default=None,
+                   help="force N host (CPU) devices")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--resize-at", type=int, action="append", default=None,
                    help="decode-path step index to resize at (repeatable; "
@@ -47,15 +37,23 @@ def main():
     p.add_argument("--resize-to", type=int, action="append", default=None,
                    help="worker count to resize to at the matching "
                         "--resize-at step")
-    args = p.parse_args()
+    args = p.parse_args(argv)
+    if args.host_devices:
+        # read when JAX's backend starts, which nothing has triggered yet
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") +
+            f" --xla_force_host_platform_device_count={args.host_devices}")
 
     ats, tos = args.resize_at or [], args.resize_to or []
     if len(ats) != len(tos):
         p.error("--resize-at and --resize-to must pair up")
     schedule = dict(zip(ats, tos))
 
+    from repro.launch.device import describe, enable_compile_cache
     from repro.serve import decode_demo
 
+    enable_compile_cache()
+    print(f"# device: {describe()}")
     out = decode_demo(args.arch, batch=args.batch,
                       prompt_len=args.prompt_len,
                       decode_steps=args.decode_steps,

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models.params import ParamDef
 from repro.models.layers import apply_rope, rmsnorm
-from repro.parallel.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -266,23 +265,45 @@ def _attn_apply_seq_shardmap(params, x, cfg: ArchConfig, mesh, rules, *,
                 chunk_k=cfg.attn_chunk_k, q_offset=offset)
             return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=x_spec, check_vma=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=x_spec, check_vma=False)(
         *[params[n] for n in names], x)
 
 
+def _attend_cache(q, k, v, valid, cfg: ArchConfig):
+    """q: (B, 1, H, hd) against k, v: (B, S, Hkv, hd) -> (B, 1, H, hd)."""
+    B, _, H, hd = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, 1, Hkv, H // Hkv, hd)
+    s = jnp.einsum("bqngd,bsnd->bngqs", qg, k,
+                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+    s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(jnp.dtype(cfg.dtype))
+    o = jnp.einsum("bngqs,bsnd->bqngd", p, v)
+    return o.reshape(B, 1, H, hd)
+
+
 def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
-                      cross: bool = False):
+                      cross: bool = False, row_stable: bool = False):
     """One-token decode against a KV cache.
 
     cache: {"k","v"}: (B, S_cache, Hkv, hd).  ``cache_index`` is the absolute
     position of the new token; for SWA the cache is a rolling buffer of
-    ``window`` slots.
+    ``window`` slots.  ``x`` may carry padding rows after the cache's B
+    sequences (``decode_step(row_stable=True)``); they stay out of the cache.
+
+    ``row_stable`` reads the cache one sequence at a time, so that a
+    sequence's scores do not depend on how many others the program holds.
     """
     dt = jnp.dtype(cfg.dtype)
-    B = x.shape[0]
-    pos = jnp.full((B, 1), cache_index)
+    B, rows = cache["k"].shape[0], x.shape[0]
+    pos = jnp.full((rows, 1), cache_index)
     q, k_new, v_new = _project_qkv(params, x, cfg, pos, rope=not cross)
+    if row_stable:
+        # the barrier keeps XLA from moving the slice below into the
+        # projections, which would run them on fewer rows again
+        q, k_new, v_new = jax.lax.optimization_barrier((q, k_new, v_new))
+    q, k_new, v_new = q[:B], k_new[:B], v_new[:B]
     if cross:
         k, v = cache["k"], cache["v"]
         valid = jnp.ones((k.shape[1],), bool)
@@ -304,16 +325,12 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     # -- expanding a 32k cache 16x in heads costs GiBs/device) and sequence-
     # sharded, so scores/PV contract over the sharded cache dim and XLA emits
     # the split-KV psum combine.
-    H = q.shape[2]
-    Hkv = k.shape[2]
-    G = H // Hkv
-    qg = q.reshape(B, 1, Hkv, G, cfg.head_dim)
-    s = jnp.einsum("bqngd,bsnd->bngqs", qg, k,
-                   preferred_element_type=jnp.float32) * (cfg.head_dim ** -0.5)
-    s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    o = jnp.einsum("bngqs,bsnd->bqngd", p, v)
-    o = o.reshape(B, 1, H, cfg.head_dim)
+    if row_stable:
+        o = jax.lax.map(lambda a: _attend_cache(
+            *(t[None] for t in a), valid, cfg)[0], (q, k, v))
+    else:
+        o = _attend_cache(q, k, v, valid, cfg)
+    o = jnp.pad(o, ((0, rows - B), (0, 0), (0, 0), (0, 0)))
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
     return out, cache
 

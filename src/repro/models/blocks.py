@@ -51,16 +51,18 @@ def decoder_block_apply(params, x, cfg: ArchConfig, *, positions,
 
 
 def decoder_block_decode(params, x, cfg: ArchConfig, cache, *, cache_index,
-                         cross_cache=None):
+                         cross_cache=None, row_stable: bool = False):
     """One-token decode. cache: {"k","v"}; cross_cache: precomputed enc K/V."""
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     a, cache = attn.decode_attn_apply(params["attn"], h, cfg, cache,
-                                      cache_index=cache_index)
+                                      cache_index=cache_index,
+                                      row_stable=row_stable)
     x = x + a
     if cross_cache is not None:
         h = rmsnorm(params["ln_x"], x, cfg.norm_eps)
         a, _ = attn.decode_attn_apply(params["cross"], h, cfg, cross_cache,
-                                      cache_index=cache_index, cross=True)
+                                      cache_index=cache_index, cross=True,
+                                      row_stable=row_stable)
         x = x + a
     h = rmsnorm(params["ln2"], x, cfg.norm_eps)
     if cfg.is_moe:

@@ -203,8 +203,35 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
     return cache
 
 
-def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index):
-    """One-token decode. tokens: (B, 1) int32. Returns (logits, new cache)."""
+#: sublane rows of a TPU (8, 128) tile. Below it the chip's compiler lays
+#: activations out in smaller tiles and fuses the projections differently,
+#: so a row would round differently in a smaller batch.
+ROW_TILE = 8
+
+
+def row_stable_decode(cfg: ArchConfig) -> bool:
+    """Whether ``decode_step(row_stable=True)`` covers ``cfg``: attention
+    decoders whose sequences never meet (no SSM state, no expert capacity
+    shared between tokens)."""
+    return not (cfg.is_ssm or cfg.is_hybrid or cfg.is_moe)
+
+
+def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
+                row_stable: bool = False):
+    """One-token decode. tokens: (B, 1) int32. Returns (logits, new cache).
+
+    ``row_stable`` computes each sequence the same way whatever ``B`` is:
+    the dense layers run on the batch padded to a multiple of ``ROW_TILE``
+    rows, and attention reads the cache one sequence at a time. A replica
+    that spreads its batch over more devices then keeps its tokens bit for
+    bit (``serve/replica.py``). Only where ``row_stable_decode(cfg)``.
+    """
+    B = tokens.shape[0]
+    if row_stable:
+        if not row_stable_decode(cfg):
+            raise NotImplementedError(
+                f"row-stable decode covers attention decoders, not {cfg.name}")
+        tokens = jnp.pad(tokens, ((0, -B % ROW_TILE), (0, 0)))
     x = embed(params["embed"], tokens, cfg)
 
     if cfg.is_ssm:
@@ -257,12 +284,14 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index):
             if cross is None:
                 lp, c = sc
                 h, c = blocks.decoder_block_decode(lp, h, cfg, c,
-                                                   cache_index=cache_index)
+                                                   cache_index=cache_index,
+                                                   row_stable=row_stable)
             else:
                 lp, c, cc = sc
                 h, c = blocks.decoder_block_decode(lp, h, cfg, c,
                                                    cache_index=cache_index,
-                                                   cross_cache=cc)
+                                                   cross_cache=cc,
+                                                   row_stable=row_stable)
             return h, c
 
         x, new_kv = jax.lax.scan(body, x, scanned)
@@ -270,4 +299,8 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index):
         cache["layers"] = new_kv
 
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    return unembed(params["embed"], x, cfg), cache
+    logits = unembed(params["embed"], x, cfg)
+    if row_stable:
+        # as in decode_attn_apply: the slice must not move into the unembed
+        logits = jax.lax.optimization_barrier(logits)
+    return logits[:B], cache

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models.params import ParamDef
-from repro.parallel.compat import shard_map
 
 
 def moe_schema(cfg: ArchConfig):
@@ -250,7 +249,7 @@ def _moe_apply_shardmap(params, x, cfg: ArchConfig, mesh, rules):
         aux = jax.lax.pmean(aux, tuple(a for a in mesh.axis_names))
         return out, aux
 
-    out, aux = shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)(
+    out, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
         params["router"], params["wi_gate"], params["wi_up"], params["wo"], x)
     return out, aux

@@ -150,10 +150,12 @@ def _mask_padded_vocab(logits, cfg: ArchConfig):
     return jnp.where(ids[None, :] < cfg.vocab_size, logits, -jnp.inf)
 
 
-def make_serve_step(cfg: ArchConfig):
-    """One-token batched decode: (params, cache, tokens, index) -> ..."""
+def make_serve_step(cfg: ArchConfig, row_stable: bool = False):
+    """One-token batched decode: (params, cache, tokens, index) -> ...
+    (``row_stable``: see ``models.model.decode_step``)."""
     def serve_step(params, cache, tokens, cache_index):
-        logits, cache = M.decode_step(params, cfg, tokens, cache, cache_index)
+        logits, cache = M.decode_step(params, cfg, tokens, cache, cache_index,
+                                      row_stable=row_stable)
         masked = _mask_padded_vocab(logits[:, -1, :], cfg)
         next_tok = jnp.argmax(masked, axis=-1).astype(jnp.int32)
         return next_tok[:, None], cache

@@ -2,6 +2,11 @@
 
 ``make_production_mesh`` is a FUNCTION (never a module constant) so importing
 this module never touches jax device state — required by the dry-run contract.
+
+Every mesh of the repo is built here, with ``Auto`` axes: the model code pins
+activations with ``with_sharding_constraint`` (``parallel/context.py``),
+which JAX accepts only on ``Auto`` axes, while ``jax.make_mesh`` defaults to
+``Explicit`` ones.
 """
 from __future__ import annotations
 
@@ -9,13 +14,21 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes (see the module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def factor_mesh(n: int, max_model: int = 16) -> Tuple[int, int]:
@@ -33,7 +46,8 @@ def make_job_mesh(devices: Sequence, *, max_model: int = 16) -> Mesh:
     n = len(devices)
     data, model = factor_mesh(n, max_model)
     dev = np.asarray(devices, dtype=object).reshape(data, model)
-    return Mesh(dev, ("data", "model"))
+    return Mesh(dev, ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def host_devices(n: Optional[int] = None):

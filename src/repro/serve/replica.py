@@ -77,11 +77,15 @@ def make_decode_app(cfg, *, batch: int, cache_len: int, seed: int = 0):
     """The serving step as a ``dmr.App``: resize point = decode-step
     boundary.
 
-    State pytree: ``{"params", "cache", "tok", "pos"}``.  Params stay
-    replicated (the ``{"params": "replicate"}`` pattern); cache leaves
+    State pytree: ``{"params", "cache", "tok", "pos"}``.  Params, held
+    in ``cfg.dtype``, stay replicated (the ``{"params": "replicate"}``
+    pattern); cache leaves
     shard along their batch axis across the whole mesh whenever
     ``batch`` divides the device count, and the redistribution registry
-    moves them on resize like any other job state.  ``step(state, i,
+    moves them on resize like any other job state.  Each device decodes
+    its own rows; for attention decoders the step is row-stable
+    (``models.model.decode_step``), so a resize keeps the tokens bit for
+    bit on the chip as well.  ``step(state, i,
     feed)`` consumes ``feed`` (a ``(batch,)`` int array of prompt
     tokens) when given — prefill-by-decode — and the previous step's
     argmax otherwise; it returns ``(state, next_tokens)``.
@@ -123,8 +127,12 @@ def make_decode_app(cfg, *, batch: int, cache_len: int, seed: int = 0):
 
     def _init(mesh):
         ss = _shardings(mesh)
-        params = jax.device_put(
-            M.init_params(cfg, jax.random.PRNGKey(seed)), ss["params"])
+        # served weights are held in the compute dtype: every use casts to
+        # it, and f32 masters would double the replica's HBM
+        params = jax.jit(
+            lambda: jax.tree.map(lambda p: p.astype(cfg.dtype),
+                                 M.init_params(cfg, jax.random.PRNGKey(seed))),
+            out_shardings=ss["params"])()
         cache = jax.device_put(
             M.init_cache(cfg, batch, cache_len, enc_len=cache_len),
             ss["cache"])
@@ -136,7 +144,9 @@ def make_decode_app(cfg, *, batch: int, cache_len: int, seed: int = 0):
         # one jitted closure per mesh: the runner swaps executables on
         # resize, and a shared trace would bake in the first mesh
         ss = _shardings(mesh)
-        serve_impl = make_serve_step(cfg)
+        specs = jax.tree.map(lambda s: s.spec, ss)
+        serve_impl = make_serve_step(cfg,
+                                     row_stable=M.row_stable_decode(cfg))
 
         def _advance(state):
             nxt, cache = serve_impl(state["params"], state["cache"],
@@ -144,8 +154,13 @@ def make_decode_app(cfg, *, batch: int, cache_len: int, seed: int = 0):
             return {"params": state["params"], "cache": cache,
                     "tok": nxt, "pos": state["pos"] + 1}
 
-        advance = jax.jit(_advance, in_shardings=(ss,), out_shardings=ss,
-                          donate_argnums=(0,))
+        # every device runs the one-device step on its own rows; with a
+        # row-stable step a sequence's tokens then do not depend on how
+        # many devices share the batch
+        advance = jax.jit(
+            jax.shard_map(_advance, mesh=mesh, in_specs=(specs,),
+                          out_specs=specs, check_vma=False),
+            in_shardings=(ss,), out_shardings=ss, donate_argnums=(0,))
 
         def step_fn(state, i, feed=None):
             if feed is not None:
@@ -172,9 +187,11 @@ def decode_demo(arch: str, *, batch: int = 4, prompt_len: int = 16,
     decode-step boundaries through ``dmr.reconfig``.
 
     ``schedule`` is a ``{step: target_workers}`` dict (``dmr.connect``'s
-    scripted form); the default resizes nobody.  Returns ``{"tokens":
-    (batch, decode_steps) array, "events": [ResizeEvent...], "sizes":
-    [(step, workers)...], "prefill_s", "decode_s"}``.
+    scripted form); the default resizes nobody.  The replica starts on
+    one device.  Returns ``{"tokens": (batch, decode_steps) array,
+    "events": [ResizeEvent...], "sizes": [(step, workers)...],
+    "prefill_s", "decode_s", "state"}`` (``state``: the final decode
+    state, on the replica's last mesh).
     """
     import time
 
@@ -186,7 +203,7 @@ def decode_demo(arch: str, *, batch: int = 4, prompt_len: int = 16,
     cfg = get_config(arch)
     devices = list(devices) if devices is not None else jax.devices()
     hi = 1 << (len(devices).bit_length() - 1)         # largest pow2 <= pool
-    params = MalleabilityParams(1, hi, min(hi, max(1, hi // 2)))
+    params = MalleabilityParams(1, hi, 1)             # start on one device
     app = make_decode_app(cfg, batch=batch, cache_len=cache_len, seed=seed)
     runner = dmr.MalleableRunner(app, params, rms=dict(schedule or {}),
                                  devices=devices[:hi])
@@ -214,7 +231,8 @@ def decode_demo(arch: str, *, batch: int = 4, prompt_len: int = 16,
     decode_s = time.perf_counter() - t0
     tokens = np.stack(outs[:decode_steps], axis=1)
     return {"tokens": tokens, "events": list(runner.events),
-            "sizes": sizes, "prefill_s": prefill_s, "decode_s": decode_s}
+            "sizes": sizes, "prefill_s": prefill_s, "decode_s": decode_s,
+            "state": state}
 
 
 # ======================================================================

@@ -8,8 +8,9 @@ import warnings; warnings.filterwarnings("ignore")
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hloanalysis import analyze_hlo
+from repro.parallel.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 
 def model(x, w):
     def body(c, wi):

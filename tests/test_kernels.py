@@ -1,4 +1,7 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode): shape/dtype sweeps."""
+"""Pallas kernels vs pure-jnp oracles (interpret mode): shape/dtype sweeps.
+
+The compiled kernels are checked against the chip's compiler in
+tests/test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +33,7 @@ def test_flash_attention(B, H, Hkv, Sq, Sk, D, causal, window, dtype):
     k = _rand((B, Hkv, Sk, D), dtype)
     v = _rand((B, Hkv, Sk, D), dtype)
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=64, block_k=64)
+                              block_q=64, block_k=64, interpret=True)
     exp = ref.attention_reference(q, k, v, causal=causal, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -57,7 +60,8 @@ def test_flash_attention_decode_step(B, H, Hkv, Sk, D):
     q = _rand((B, H, 1, D), jnp.float32)
     k = _rand((B, Hkv, Sk, D), jnp.float32)
     v = _rand((B, Hkv, Sk, D), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=False, block_q=64, block_k=64)
+    out = ops.flash_attention(q, k, v, causal=False, block_q=64, block_k=64,
+                              interpret=True)
     exp = ref.attention_reference(q, k, v, causal=False, window=0)
     assert out.shape == (B, H, 1, D)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -73,11 +77,12 @@ def test_flash_attention_decode_consistent_as_cache_grows():
     k = _rand((B, H, S, D), jnp.float32)
     v = _rand((B, H, S, D), jnp.float32)
     full = ops.flash_attention(q_full, k, v, causal=True,
-                               block_q=64, block_k=64)
+                               block_q=64, block_k=64, interpret=True)
     for pos in (64, 128, 192):
         step = ops.flash_attention(q_full[:, :, pos - 1:pos, :],
                                    k[:, :, :pos, :], v[:, :, :pos, :],
-                                   causal=False, block_q=64, block_k=64)
+                                   causal=False, block_q=64, block_k=64,
+                                   interpret=True)
         np.testing.assert_allclose(np.asarray(step[:, :, 0, :]),
                                    np.asarray(full[:, :, pos - 1, :]),
                                    atol=2e-5, rtol=2e-5)
@@ -97,7 +102,7 @@ def test_ssd_scan(B, H, S, P, N, Q, dtype):
     a = -jnp.abs(_rand((B, H, S), jnp.float32)) * 0.4
     bm = _rand((B, S, N), dtype) * 0.3
     cm = _rand((B, S, N), dtype) * 0.3
-    out = ops.ssd_scan(xdt, a, bm, cm, chunk=Q)
+    out = ops.ssd_scan(xdt, a, bm, cm, chunk=Q, interpret=True)
     exp = ref.ssd_reference(xdt, a, bm, cm)
     tol = 3e-2 if dtype == jnp.bfloat16 else 5e-4
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -116,7 +121,7 @@ def test_ssd_matches_model_chunked():
     y_model, _ = ssd_chunked(x, dt, A, bm, cm, chunk=32)
     xdt = jnp.moveaxis(x * dt[..., None], 1, 2)              # (B,H,S,P)
     a = jnp.moveaxis(dt * A[None, None, :], 1, 2)
-    y_kernel = ops.ssd_scan(xdt, a, bm, cm, chunk=32)
+    y_kernel = ops.ssd_scan(xdt, a, bm, cm, chunk=32, interpret=True)
     np.testing.assert_allclose(np.moveaxis(np.asarray(y_kernel), 1, 2),
                                np.asarray(y_model), atol=5e-4, rtol=5e-4)
 
@@ -126,6 +131,6 @@ def test_ssd_matches_model_chunked():
 def test_repack(nblocks, block, width, nout):
     src = _rand((nblocks, block, width), jnp.float32)
     idx = jnp.asarray(RNG.permutation(nblocks)[:nout], jnp.int32)
-    out = ops.repack(src, idx)
+    out = ops.repack(src, idx, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(ref.repack_reference(src, idx)))

@@ -55,6 +55,54 @@ def test_decode_step(arch):
     assert int(tok.max()) < cfg.vocab_size        # padded ids masked
 
 
+ROW_STABLE = [a for a in list_archs()
+              if M.row_stable_decode(get_config(a + "-smoke"))]
+
+
+def _decode(cfg, params, rows, row_stable, steps=3):
+    """Logits of ``steps`` greedy decode steps for prompts ``rows``."""
+    step = jax.jit(lambda p, t, c, i: M.decode_step(p, cfg, t, c, i,
+                                                    row_stable=row_stable))
+    cache = M.init_cache(cfg, len(rows), 16, enc_len=16)
+    tok = jnp.asarray(rows, jnp.int32)[:, None]
+    out = []
+    for i in range(steps):
+        logits, cache = step(params, tok, cache, jnp.int32(i))
+        out.append(np.asarray(logits))
+        tok = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1)[:, None]
+    return np.stack(out), cache
+
+
+@pytest.mark.parametrize("arch", ROW_STABLE)
+def test_row_stable_decode(arch):
+    """The row-stable step (batch padded to ROW_TILE rows, the cache read
+    one sequence at a time) gives the plain step's logits and cache, and a
+    sequence's logits do not depend on its batch-mates."""
+    cfg = get_config(arch + "-smoke")
+    params = init_state(cfg, OPT, 0).params
+    rows = [3, 1, 4]                     # 3 rows: 5 padding rows in play
+    stable, cache = _decode(cfg, params, rows, True)
+    plain, plain_cache = _decode(cfg, params, rows, False)
+    assert stable.shape == plain.shape
+    np.testing.assert_allclose(stable, plain, rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(plain_cache)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=1e-4, atol=1e-5)
+    split = np.concatenate([_decode(cfg, params, rows[:1], True)[0],
+                            _decode(cfg, params, rows[1:], True)[0]], axis=1)
+    np.testing.assert_array_equal(split, stable)
+
+
+def test_row_stable_decode_refuses_shared_state():
+    cfg = get_config("mamba2-370m-smoke")
+    assert not M.row_stable_decode(cfg)
+    with pytest.raises(NotImplementedError, match="row-stable"):
+        M.decode_step(init_state(cfg, OPT, 0).params, cfg,
+                      jnp.zeros((2, 1), jnp.int32), M.init_cache(cfg, 2, 16),
+                      0, row_stable=True)
+
+
 def test_vlm_prefix_loss_span():
     cfg = get_config("pixtral-12b-smoke")
     batch = {k: jnp.asarray(v) for k, v in make_batch(cfg, SMOKE_SHAPE).items()}

@@ -14,9 +14,10 @@ from repro.configs import get_config
 from repro.models import moe as MOE
 from repro.models.params import init as pinit
 from repro.parallel.context import sharding_context
+from repro.parallel.mesh import make_mesh
 from repro.parallel.sharding import rules_for
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 
 for arch, ep in [("qwen3-moe-235b-a22b", True), ("mixtral-8x7b", False)]:

@@ -12,10 +12,11 @@ from repro.configs import get_config
 from repro.models import attention as A
 from repro.models.params import init as pinit
 from repro.parallel.context import sharding_context
+from repro.parallel.mesh import make_mesh
 from repro.parallel.sharding import rules_for
 
 cfg = get_config("qwen2.5-32b-smoke")    # 4 heads, kv=2, qkv_bias=True
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 params = pinit(A.attention_schema(cfg), jax.random.PRNGKey(0))
 x = jnp.asarray(np.random.default_rng(0).standard_normal(
     (2, 64, cfg.d_model)), jnp.float32)

@@ -5,8 +5,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.configs import get_config
 from repro.parallel.sharding import DEFAULT_RULES, rules_for, spec_for_axes
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_mapping():

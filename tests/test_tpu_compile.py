@@ -1,0 +1,95 @@
+"""The Pallas kernels compile for a described TPU v5e chip at model widths.
+
+Nothing runs: the chip's compiler is installed here and compiles for a chip
+that is described, not attached. It refuses block shapes that break the
+tiling rules and kernels that overflow fast memory, which interpret mode
+(tests/test_kernels.py) cannot see. The widths are granite-3-2b's attention
+(D=64, 32 query / 8 KV heads) and mamba2-370m's SSD (32 heads, P=64, N=128,
+chunk 256).
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and pytest-xdist workers each import every test file.
+"""
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                 # no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(lowered):
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch,sq", [(1, 2048), (8, 1)],
+                         ids=["prefill_2048", "decode_sq1"])
+def test_flash_attention_compiles(one_chip, batch, sq):
+    q = _spec(one_chip, (batch, 32, sq, 64))
+    kv = _spec(one_chip, (batch, 8, 2048, 64))
+    _assert_kernel(ops.flash_attention.lower(q, kv, kv, causal=sq > 1))
+
+
+def test_ssd_scan_compiles(one_chip):
+    B, H, S, P, N = 1, 32, 2048, 64, 128
+    _assert_kernel(ops.ssd_scan.lower(
+        _spec(one_chip, (B, H, S, P)),
+        _spec(one_chip, (B, H, S), jnp.float32),
+        _spec(one_chip, (B, S, N)), _spec(one_chip, (B, S, N)), chunk=256))
+
+
+def test_repack_compiles(one_chip):
+    _assert_kernel(ops.repack.lower(
+        _spec(one_chip, (64, 8, 2048), jnp.float32),
+        _spec(one_chip, (64,), jnp.int32)))
+
+
+def _matmul_shapes(cfg, sharding, rows):
+    """Output shapes of the matmuls (lowered to convolutions) in the chip's
+    program for a row-stable decode step over ``rows`` sequences."""
+    from repro.models import model as M
+    from repro.models.train import make_serve_step
+
+    def spec(a):
+        return _spec(sharding, a.shape, a.dtype)
+
+    params = jax.tree.map(lambda a: _spec(sharding, a.shape),
+                          M.abstract_params(cfg))
+    cache = jax.tree.map(spec, jax.eval_shape(
+        lambda: M.init_cache(cfg, rows, 128)))
+    text = jax.jit(make_serve_step(cfg, row_stable=True)).lower(
+        params, cache, _spec(sharding, (rows, 1), jnp.int32),
+        _spec(sharding, (), jnp.int32)).compile().as_text()
+    return sorted(re.findall(r"= (\w+\[[\d,]*\])\S* convolution\(", text))
+
+
+def test_row_stable_decode_runs_the_same_matmuls(one_chip):
+    """A granite-3-2b replica's device holds 8, 4 or 2 of its 8 sequences on
+    1, 2 or 4 chips. The row-stable step runs every matmul at the same shape
+    and dtype whatever the share (the compiler must not move the row slice
+    into a projection), so the chip computes each sequence the same way."""
+    from repro.configs import get_config
+    cfg = get_config("granite-3-2b")
+    shapes = {rows: _matmul_shapes(cfg, one_chip, rows) for rows in (8, 4, 2)}
+    assert shapes[8] and shapes[4] == shapes[8] and shapes[2] == shapes[8]
