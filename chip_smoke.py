@@ -57,25 +57,18 @@ DECODE_RESIZES = {PROMPT + 8: 2, PROMPT + 20: 4}
 LOSS_RTOL = 2e-3
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, from its own
-    monitoring events."""
+def compile_lap():
+    """A function that returns the seconds JAX spent tracing, lowering and
+    compiling (``repro.core.spans.COMPILE``) since its previous call."""
+    from repro.core.spans import COMPILE
+    last = COMPILE.seconds
 
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def lap(self) -> float:
-        s, self.seconds = self.seconds, 0.0
+    def lap() -> float:
+        nonlocal last
+        now = COMPILE.seconds
+        s, last = now - last, now
         return s
+    return lap
 
 
 def emit(record: dict) -> None:
@@ -107,10 +100,11 @@ def check_tokens(tokens, vocab, shape):
 def event_records(events):
     return [{"step": e.step, "action": e.action, "from": e.from_procs,
              "to": e.to_procs, "bytes_moved": e.transfer.bytes_moved,
-             "transfer_s": e.transfer.seconds} for e in events]
+             "transfer_s": e.transfer.seconds, "compile_s": e.compile_s,
+             "swap_s": e.recompile_s} for e in events]
 
 
-def one_chip(clock, dev):
+def one_chip(lap, dev):
     from repro.configs import get_config
     from repro.launch import train
 
@@ -121,7 +115,7 @@ def one_chip(clock, dev):
     del out["state"]
     emit({"phase": "train", "arch": TRAIN_ARCH, "seq": TRAIN_SEQ,
           "global_batch": TRAIN_BATCH, "workers": 1,
-          "compile_s": clock.lap(), "first_step_s": out["step_s"][0],
+          "compile_s": lap(), "first_step_s": out["step_s"][0],
           "timed_step_s": out["step_s"][WARMUP:], "losses": out["losses"],
           "peak_bytes_in_use": peak_bytes(dev)})
 
@@ -130,7 +124,7 @@ def one_chip(clock, dev):
     check_tokens(res["tokens"], cfg.vocab_size, (SERVE_BATCH, DECODE))
     emit({"phase": "serve", "arch": SERVE_ARCH, "batch": SERVE_BATCH,
           "prompt": PROMPT, "decode_tokens": DECODE, "cache_len": CACHE,
-          "compile_s": clock.lap(),
+          "compile_s": lap(),
           "prefill_s_incl_compile": res["prefill_s"],
           "decode_s_per_token": res["decode_s"] / DECODE,
           "peak_bytes_in_use": peak_bytes(dev)})
@@ -141,7 +135,7 @@ def state_device_ids(tree):
                    for d in x.sharding.device_set})
 
 
-def four_chips(clock, devs):
+def four_chips(lap, devs):
     from repro.launch import train
 
     resizes = [a for s, n in TRAIN_RESIZES.items()
@@ -161,7 +155,7 @@ def four_chips(clock, devs):
           "state_devices": ela["state_devices"],
           "losses": ela["losses"], "fixed_losses": ref["losses"],
           "max_rel_diff": max(rel), "rtol": LOSS_RTOL,
-          "events": event_records(ela["events"]), "compile_s": clock.lap()})
+          "events": event_records(ela["events"]), "compile_s": lap()})
     if [e.to_procs for e in ela["events"]] != list(TRAIN_RESIZES.values()):
         raise AssertionError(f"resizes {event_records(ela['events'])}")
     if ela["state_devices"] != ela["workers"]:
@@ -186,7 +180,7 @@ def four_chips(clock, devs):
                                  for r in same],
           "decode_s_per_token": grown["decode_s"] / DECODE,
           "fixed_decode_s_per_token": fixed["decode_s"] / DECODE,
-          "events": event_records(grown["events"]), "compile_s": clock.lap()})
+          "events": event_records(grown["events"]), "compile_s": lap()})
     if sizes != [1, 2, 4]:
         raise AssertionError(f"replica sizes {sizes}")
     if ids != sorted(d.id for d in devs):
@@ -223,11 +217,11 @@ def main(argv=None) -> int:
     cache = enable_compile_cache()
     device = describe(devices)
     emit({"device": device, "compile_cache": cache, "jax": jax.__version__})
-    clock = CompileClock()
+    lap = compile_lap()
     if args.chips == 1:
-        one_chip(clock, devices[0])
+        one_chip(lap, devices[0])
     else:
-        four_chips(clock, devices)
+        four_chips(lap, devices)
     emit({"ok": True, "device": device})
     return 0
 

@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.core.redistribute import (TransferStats, blockcyclic_redistribute,
                                      default_redistribution)
+from repro.core.spans import span
 
 PatternSpec = Union[str, "Pattern", Callable]
 
@@ -323,8 +324,9 @@ def redistribute_tree(state, new_shardings, *,
     per_pattern: Dict[str, TransferStats] = {}
     for pat_id, idxs in groups.items():
         pat = by_id[pat_id]
-        moved, stats = pat.apply([paths_leaves[i][1] for i in idxs],
-                                 [shard_leaves[i] for i in idxs], ctx)
+        with span("dmr.redistribute", pattern=pat.spec()):
+            moved, stats = pat.apply([paths_leaves[i][1] for i in idxs],
+                                     [shard_leaves[i] for i in idxs], ctx)
         for i, leaf in zip(idxs, moved):
             out_leaves[i] = leaf
         key, n = pat.spec(), 2

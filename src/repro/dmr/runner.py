@@ -35,6 +35,7 @@ import jax
 from repro.core.params import MalleabilityParams
 from repro.core.policy import Action, ClusterView, get_policy
 from repro.core.redistribute import TransferStats
+from repro.core.spans import COMPILE, span
 from repro.dmr.app import MalleableApp, ensure_app
 from repro.dmr.connectors import PolicyRMS, RMSConnector, connect
 from repro.dmr.patterns import PatternSpec, redistribute_tree
@@ -48,12 +49,20 @@ class ResizeEvent:
     from_procs: int
     to_procs: int
     transfer: TransferStats
+    #: seconds to look up or build the new mesh's step function: the
+    #: executable swap. ``jax.jit`` is lazy, so this does not include XLA
+    #: compiling the step; ``compile_s`` does.
     recompile_s: float
     #: TransferStats per named redistribution pattern (keyed by pattern
     #: spec, e.g. "default" / "blockcyclic:4"); empty for a legacy
     #: whole-tree custom redistribute callable.
     per_pattern: Dict[str, TransferStats] = dataclasses.field(
         default_factory=dict)
+    #: seconds JAX spent tracing, lowering and compiling from the start of
+    #: the resize to the end of the first step on the new mesh, where the
+    #: step compiles (``spans.COMPILE``); filled in by that step, 0 for a
+    #: size whose step already ran.
+    compile_s: float = 0.0
 
 
 class MalleableRunner:
@@ -128,6 +137,9 @@ class MalleableRunner:
         self.event_listener = event_listener
         self._last_query_step = -10 ** 9
         self._last_query_time = 0.0
+        #: the last resize's event and ``COMPILE.seconds`` at its start,
+        #: until the first step on the new mesh fills in ``compile_s``
+        self._compiling: Optional[tuple] = None
 
     # ------------------------------------------------------------------
     def _mesh_for(self, n: int):
@@ -240,18 +252,19 @@ class MalleableRunner:
         """Algorithm 1: check role/inhibitors, query RMS, resize if told to."""
         if not self.query_due(step):
             return state
-        self._last_query_step = step
-        self._last_query_time = time.monotonic()
-
-        action = self.rms.query(step=step, current=self.current,
-                                params=self.params)
+        with span("dmr.reconfig", step=step):
+            self._last_query_step = step
+            self._last_query_time = time.monotonic()
+            action = self.rms.query(step=step, current=self.current,
+                                    params=self.params)
         if action.kind == "none" or action.target == self.current:
             return state
         return self.apply_resize(state, step, action)
 
     def _redistribute(self, state, new_shardings, target: int):
         if self._custom_redistribute is not None:
-            state, stats = self._custom_redistribute(state, new_shardings)
+            with span("dmr.redistribute", pattern="custom"):
+                state, stats = self._custom_redistribute(state, new_shardings)
             return state, stats, {}
         return redistribute_tree(state, new_shardings,
                                  patterns=self.patterns,
@@ -273,18 +286,22 @@ class MalleableRunner:
         target = self._pool_clamp(self.params.clamp(action.target))
         if target == self.current and not force:
             return state
-        new_mesh = self._mesh_for(target)
-        new_shardings = self.app.state_shardings(new_mesh)
-        state, stats, per_pattern = self._redistribute(state, new_shardings,
-                                                       target)
-        t0 = time.perf_counter()
-        self._step_fn(target)          # compile (cached across resizes)
-        recompile = time.perf_counter() - t0
+        with span("dmr.resize", step=step, procs=target):
+            compiled = COMPILE.seconds
+            new_mesh = self._mesh_for(target)
+            new_shardings = self.app.state_shardings(new_mesh)
+            state, stats, per_pattern = self._redistribute(
+                state, new_shardings, target)
+            with span("dmr.swap", step=step):
+                t0 = time.perf_counter()
+                self._step_fn(target)  # cached across resizes; jit is lazy
+                recompile = time.perf_counter() - t0
         kind = action.kind if target != self.current else "migrate"
         event = ResizeEvent(
             step=step, action=kind, from_procs=self.current,
             to_procs=target, transfer=stats, recompile_s=recompile,
             per_pattern=per_pattern)
+        self._compiling = (event, compiled)
         self.events.append(event)
         if self.event_listener is not None:
             self.event_listener(event)
@@ -294,7 +311,13 @@ class MalleableRunner:
 
     # ------------------------------------------------------------------
     def step(self, state, step: int, *args):
-        return self._step_fn(self.current)(state, step, *args)
+        with span("dmr.step", step=step):
+            out = self._step_fn(self.current)(state, step, *args)
+        if self._compiling is not None:
+            event, compiled = self._compiling
+            self._compiling = None
+            event.compile_s = COMPILE.seconds - compiled
+        return out
 
     # fault tolerance: forced shrink onto survivors (DESIGN.md §6)
     def handle_failure(self, state, step: int, failed_devices) -> Any:

@@ -25,7 +25,14 @@ def enable_compile_cache() -> str:
     other directory is set here. Otherwise the cache is the fixed path
     ``<checkout>/.jax_cache``: the path is part of the cache key, so a
     directory that moved would never hit.
+
+    The key holds the programs' metadata: an executable read back carries
+    the ``op_name`` scopes of the code that asks for it, which a profile
+    reads (``models/``'s ``jax.named_scope``s). By JAX's default, code that
+    differs only in its scopes would get an executable compiled for other
+    scopes, and its profile would show those.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

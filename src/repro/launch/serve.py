@@ -71,7 +71,8 @@ def main(argv: Optional[Sequence[str]] = None):
         print(f"# resize @ step {ev.step}: {ev.action} "
               f"{ev.from_procs}->{ev.to_procs} "
               f"({ev.transfer.bytes_moved/1e6:.1f} MB moved, "
-              f"recompile {ev.recompile_s*1e3:.0f} ms)")
+              f"compile {ev.compile_s*1e3:.0f} ms, "
+              f"swap {ev.recompile_s*1e3:.1f} ms)")
     for b in range(min(args.batch, 4)):
         print(f"seq[{b}]: {toks[b].tolist()}")
 

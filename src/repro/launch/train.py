@@ -108,7 +108,8 @@ def run(args: argparse.Namespace, devices: Optional[List] = None,
     for e in runner.events:
         log(f"# resize @step {e.step}: {e.action} {e.from_procs}->"
             f"{e.to_procs}, moved {e.transfer.bytes_moved/1e6:.1f} MB in "
-            f"{e.transfer.seconds*1e3:.1f} ms, recompile {e.recompile_s:.2f}s")
+            f"{e.transfer.seconds*1e3:.1f} ms, compile {e.compile_s:.2f} s, "
+            f"swap {e.recompile_s*1e3:.1f} ms")
     return {"losses": losses, "step_s": step_s, "workers": workers,
             "state_devices": state_devices, "events": list(runner.events),
             "state": state}

@@ -46,6 +46,7 @@ def attention_schema(cfg: ArchConfig):
 # Projections
 # ----------------------------------------------------------------------
 
+@jax.named_scope("attn_qkv")
 def _project_qkv(params, x, cfg: ArchConfig, positions, kv_x=None,
                  rope: bool = True):
     dt = jnp.dtype(cfg.dtype)
@@ -95,6 +96,7 @@ def repeat_kv(k, num_heads: int):
     return jnp.repeat(k, G, axis=2)
 
 
+@jax.named_scope("attend")
 def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                       chunk_q: int = 1024, chunk_k: int = 1024,
                       q_offset: int = 0):
@@ -199,7 +201,8 @@ def attn_apply(params, x, cfg: ArchConfig, *, positions, kv_x=None,
     window = cfg.window if cfg.attention == "swa" else 0
     out = chunked_attention(q, k, v, causal=causal, window=window,
                             chunk_q=cfg.attn_chunk_q, chunk_k=cfg.attn_chunk_k)
-    return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
+    with jax.named_scope("attn_out"):
+        return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
 
 
 def _attn_apply_seq_shardmap(params, x, cfg: ArchConfig, mesh, rules, *,
@@ -263,7 +266,8 @@ def _attn_apply_seq_shardmap(params, x, cfg: ArchConfig, mesh, rules, *,
                 q, k, v, causal=causal, window=window,
                 chunk_q=min(cfg.attn_chunk_q, S_loc),
                 chunk_k=cfg.attn_chunk_k, q_offset=offset)
-            return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+            with jax.named_scope("attn_out"):
+                return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
     return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=x_spec, check_vma=False)(
@@ -310,28 +314,32 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     else:
         S = cache["k"].shape[1]
         slot = jnp.mod(cache_index, S) if cfg.attention == "swa" else cache_index
-        k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype),
-                                         (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype),
-                                         (0, slot, 0, 0))
+        with jax.named_scope("kv_write"):
+            k = jax.lax.dynamic_update_slice(
+                cache["k"], k_new.astype(cache["k"].dtype), (0, slot, 0, 0))
+            v = jax.lax.dynamic_update_slice(
+                cache["v"], v_new.astype(cache["v"].dtype), (0, slot, 0, 0))
         cache = {"k": k, "v": v}
-        kpos = jnp.arange(S)
-        if cfg.attention == "swa":
-            valid = jnp.ones((S,), bool)       # rolling buffer: all slots live
-        else:
-            valid = kpos <= cache_index
+        with jax.named_scope("attend"):        # the mask is attention's
+            kpos = jnp.arange(S)
+            if cfg.attention == "swa":
+                valid = jnp.ones((S,), bool)   # rolling buffer: all slots live
+            else:
+                valid = kpos <= cache_index
     # split-KV (flash-decoding) attention: q is tiny (one token) and stays
     # replicated over the model axis; the cache remains GROUPED (no repeat_kv
     # -- expanding a 32k cache 16x in heads costs GiBs/device) and sequence-
     # sharded, so scores/PV contract over the sharded cache dim and XLA emits
     # the split-KV psum combine.
-    if row_stable:
-        o = jax.lax.map(lambda a: _attend_cache(
-            *(t[None] for t in a), valid, cfg)[0], (q, k, v))
-    else:
-        o = _attend_cache(q, k, v, valid, cfg)
-    o = jnp.pad(o, ((0, rows - B), (0, 0), (0, 0), (0, 0)))
-    out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
+    with jax.named_scope("attend"):
+        if row_stable:
+            o = jax.lax.map(lambda a: _attend_cache(
+                *(t[None] for t in a), valid, cfg)[0], (q, k, v))
+        else:
+            o = _attend_cache(q, k, v, valid, cfg)
+    with jax.named_scope("attn_out"):
+        o = jnp.pad(o, ((0, rows - B), (0, 0), (0, 0), (0, 0)))
+        out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
     return out, cache
 
 

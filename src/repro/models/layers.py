@@ -18,6 +18,7 @@ def rmsnorm_schema(dim: int, cfg: ArchConfig):
     return {"scale": ParamDef((dim,), ("norm",), dtype=cfg.param_dtype, init="ones")}
 
 
+@jax.named_scope("norm")
 def rmsnorm(params, x, eps: float):
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
@@ -64,6 +65,7 @@ def mlp_schema(cfg: ArchConfig, d_in: Optional[int] = None,
     }
 
 
+@jax.named_scope("mlp")
 def mlp(params, x, cfg: ArchConfig):
     from repro.parallel.context import constrain
     dt = jnp.dtype(cfg.dtype)
@@ -95,11 +97,13 @@ def embed_schema(cfg: ArchConfig):
     return s
 
 
+@jax.named_scope("embed")
 def embed(params, tokens, cfg: ArchConfig):
     table = params["embedding"].astype(jnp.dtype(cfg.dtype))
     return jnp.take(table, tokens, axis=0)
 
 
+@jax.named_scope("unembed")
 def unembed(params, x, cfg: ArchConfig):
     from repro.parallel.context import constrain
     dt = jnp.dtype(cfg.dtype)

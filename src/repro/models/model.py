@@ -160,7 +160,8 @@ def forward_hidden(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray]):
         enc_out = _encode(params, batch["frames"], cfg)
 
     positions = jnp.arange(x.shape[1])[None, :]
-    x, aux = _trunk(params, x, cfg, positions, enc_out=enc_out)
+    with jax.named_scope("layers"):
+        x, aux = _trunk(params, x, cfg, positions, enc_out=enc_out)
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     return x, aux
 
@@ -239,7 +240,9 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
             lp, c = scanned
             h, c = blocks.ssm_block_decode(lp, h, cfg, c)
             return h, c
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+        with jax.named_scope("layers"):
+            x, new_cache = jax.lax.scan(body, x,
+                                        (params["layers"], cache["layers"]))
         cache = {"layers": new_cache}
 
     elif cfg.is_hybrid:
@@ -267,8 +270,9 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
             h = h + blocks.mlp(shared["mlp"], hn, cfg)
             return h, (gc, kv)
 
-        x, (new_gc, new_kv) = jax.lax.scan(
-            group_body, x, (grouped, grouped_cache, cache["shared_kv"]))
+        with jax.named_scope("layers"):
+            x, (new_gc, new_kv) = jax.lax.scan(
+                group_body, x, (grouped, grouped_cache, cache["shared_kv"]))
         cache = {
             "layers": jax.tree.map(
                 lambda t: t.reshape(cfg.num_layers, *t.shape[2:]), new_gc),
@@ -294,7 +298,8 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
                                                    row_stable=row_stable)
             return h, c
 
-        x, new_kv = jax.lax.scan(body, x, scanned)
+        with jax.named_scope("layers"):
+            x, new_kv = jax.lax.scan(body, x, scanned)
         cache = dict(cache)
         cache["layers"] = new_kv
 

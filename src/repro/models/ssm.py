@@ -54,6 +54,7 @@ def _causal_conv(x, w, state=None):
     return out, new_state
 
 
+@jax.named_scope("ssd_scan")
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, state0=None):
     """SSD sequence transform.
 

@@ -156,8 +156,9 @@ def make_serve_step(cfg: ArchConfig, row_stable: bool = False):
     def serve_step(params, cache, tokens, cache_index):
         logits, cache = M.decode_step(params, cfg, tokens, cache, cache_index,
                                       row_stable=row_stable)
-        masked = _mask_padded_vocab(logits[:, -1, :], cfg)
-        next_tok = jnp.argmax(masked, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            masked = _mask_padded_vocab(logits[:, -1, :], cfg)
+            next_tok = jnp.argmax(masked, axis=-1).astype(jnp.int32)
         return next_tok[:, None], cache
 
     return serve_step

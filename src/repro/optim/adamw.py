@@ -41,6 +41,7 @@ class AdamW:
                         nu=jax.tree.map(zeros, params),
                         count=jnp.zeros((), jnp.int32))
 
+    @jax.named_scope("optimizer")
     def update(self, grads, state: OptState, params):
         count = state.count + 1
         cf = count.astype(jnp.float32)

@@ -95,6 +95,7 @@ def make_decode_app(cfg, *, batch: int, cache_len: int, seed: int = 0):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro import dmr
+    from repro.core.spans import span
     from repro.models import model as M
     from repro.models.train import make_serve_step
 
@@ -164,11 +165,13 @@ def make_decode_app(cfg, *, batch: int, cache_len: int, seed: int = 0):
 
         def step_fn(state, i, feed=None):
             if feed is not None:
-                tok = jax.device_put(
-                    jnp.asarray(feed, jnp.int32).reshape(batch, 1),
-                    ss["tok"])
+                with span("dmr.feed", step=i):
+                    tok = jax.device_put(
+                        jnp.asarray(feed, jnp.int32).reshape(batch, 1),
+                        ss["tok"])
                 state = {**state, "tok": tok}
-            state = advance(state)
+            with span("dmr.advance", step=i):
+                state = advance(state)
             return state, state["tok"]
 
         return step_fn

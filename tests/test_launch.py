@@ -27,6 +27,7 @@ def test_train_defaults_span_the_pool():
 @pytest.mark.parametrize("env", [None, "/elsewhere/cache"])
 def test_compile_cache_dir(monkeypatch, env):
     old = jax.config.jax_compilation_cache_dir
+    old_key = jax.config.jax_compilation_cache_include_metadata_in_key
     if env:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
     else:
@@ -39,8 +40,12 @@ def test_compile_cache_dir(monkeypatch, env):
         else:
             assert path == f"{device.CHECKOUT}/.jax_cache"
             assert jax.config.jax_compilation_cache_dir == path
+        # scopes are part of the key: no executable with stale op_names
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          old_key)
 
 
 def test_describe_names_the_device():
