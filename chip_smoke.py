@@ -21,9 +21,13 @@ Four chips (``--chips 4``), each compared with a run that does not resize:
 * **decode** -- a granite-3-2b replica grown 1 -> 2 -> 4 in place; its
   tokens must equal those of the run with no resize, bit for bit.
 
-Each phase prints one JSON line. The last line of stdout is
-``{"ok": true, "device": {"platform", "kind", "count"}}``, printed only when
-every phase passed. With no TPU the script exits non-zero before any phase.
+Each phase prints one JSON line, with the seconds JAX spent compiling in
+it (``compile_s``) and the number of programs XLA built or loaded from the
+persistent compile cache (``programs_built``). Against an earlier run, a
+warm cache shows as the same count in a fraction of the seconds, and a
+higher count as programs the phase did not build before. The last line of
+stdout is ``{"ok": true, "device": {"platform", "kind", "count"}}``,
+printed only when every phase passed. With no TPU the script exits non-zero before any phase.
 """
 from __future__ import annotations
 
@@ -58,16 +62,18 @@ LOSS_RTOL = 2e-3
 
 
 def compile_lap():
-    """A function that returns the seconds JAX spent tracing, lowering and
-    compiling (``repro.core.spans.COMPILE``) since its previous call."""
+    """A function that returns, since its previous call, the seconds JAX
+    spent tracing, lowering and compiling (``compile_s``) and the programs
+    XLA built or loaded from the persistent cache (``programs_built``), from
+    ``repro.core.spans.COMPILE``."""
     from repro.core.spans import COMPILE
-    last = COMPILE.seconds
+    last = COMPILE.seconds, COMPILE.compiles
 
-    def lap() -> float:
+    def lap() -> dict:
         nonlocal last
-        now = COMPILE.seconds
-        s, last = now - last, now
-        return s
+        now = COMPILE.seconds, COMPILE.compiles
+        (s, n), last = (now[0] - last[0], now[1] - last[1]), now
+        return {"compile_s": s, "programs_built": n}
     return lap
 
 
@@ -115,7 +121,7 @@ def one_chip(lap, dev):
     del out["state"]
     emit({"phase": "train", "arch": TRAIN_ARCH, "seq": TRAIN_SEQ,
           "global_batch": TRAIN_BATCH, "workers": 1,
-          "compile_s": lap(), "first_step_s": out["step_s"][0],
+          **lap(), "first_step_s": out["step_s"][0],
           "timed_step_s": out["step_s"][WARMUP:], "losses": out["losses"],
           "peak_bytes_in_use": peak_bytes(dev)})
 
@@ -124,7 +130,7 @@ def one_chip(lap, dev):
     check_tokens(res["tokens"], cfg.vocab_size, (SERVE_BATCH, DECODE))
     emit({"phase": "serve", "arch": SERVE_ARCH, "batch": SERVE_BATCH,
           "prompt": PROMPT, "decode_tokens": DECODE, "cache_len": CACHE,
-          "compile_s": lap(),
+          **lap(),
           "prefill_s_incl_compile": res["prefill_s"],
           "decode_s_per_token": res["decode_s"] / DECODE,
           "peak_bytes_in_use": peak_bytes(dev)})
@@ -155,7 +161,7 @@ def four_chips(lap, devs):
           "state_devices": ela["state_devices"],
           "losses": ela["losses"], "fixed_losses": ref["losses"],
           "max_rel_diff": max(rel), "rtol": LOSS_RTOL,
-          "events": event_records(ela["events"]), "compile_s": lap()})
+          "events": event_records(ela["events"]), **lap()})
     if [e.to_procs for e in ela["events"]] != list(TRAIN_RESIZES.values()):
         raise AssertionError(f"resizes {event_records(ela['events'])}")
     if ela["state_devices"] != ela["workers"]:
@@ -180,7 +186,7 @@ def four_chips(lap, devs):
                                  for r in same],
           "decode_s_per_token": grown["decode_s"] / DECODE,
           "fixed_decode_s_per_token": fixed["decode_s"] / DECODE,
-          "events": event_records(grown["events"]), "compile_s": lap()})
+          "events": event_records(grown["events"]), **lap()})
     if sizes != [1, 2, 4]:
         raise AssertionError(f"replica sizes {sizes}")
     if ids != sorted(d.id for d in devs):

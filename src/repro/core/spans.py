@@ -34,7 +34,9 @@ def span(name: str, **args):
 class CompileMeter:
     """Seconds JAX spends tracing, lowering and compiling programs in this
     process, and the number of programs XLA compiled or loaded from the
-    persistent compile cache, from JAX's own monitoring events."""
+    persistent compile cache, from JAX's own monitoring events.
+    ``ResizeEvent.compile_s`` reads the seconds; ``chip_smoke.py`` prints
+    both for each of its phases."""
 
     DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
                  "/jax/core/compile/jaxpr_to_mlir_module_duration",

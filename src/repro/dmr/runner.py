@@ -58,10 +58,10 @@ class ResizeEvent:
     #: whole-tree custom redistribute callable.
     per_pattern: Dict[str, TransferStats] = dataclasses.field(
         default_factory=dict)
-    #: seconds JAX spent tracing, lowering and compiling from the start of
-    #: the resize to the end of the first step on the new mesh, where the
-    #: step compiles (``spans.COMPILE``); filled in by that step, 0 for a
-    #: size whose step already ran.
+    #: seconds JAX spent tracing, lowering and compiling (``spans.COMPILE``)
+    #: inside this runner's resize and its first step on the new mesh, where
+    #: the step compiles; the step adds its part. Another runner's compiles
+    #: in between are left out. 0 for a size whose step already ran.
     compile_s: float = 0.0
 
 
@@ -137,9 +137,9 @@ class MalleableRunner:
         self.event_listener = event_listener
         self._last_query_step = -10 ** 9
         self._last_query_time = 0.0
-        #: the last resize's event and ``COMPILE.seconds`` at its start,
-        #: until the first step on the new mesh fills in ``compile_s``
-        self._compiling: Optional[tuple] = None
+        #: the last resize's event, until the first step on the new mesh
+        #: adds its compile seconds to ``compile_s``
+        self._compiling: Optional[ResizeEvent] = None
 
     # ------------------------------------------------------------------
     def _mesh_for(self, n: int):
@@ -287,7 +287,7 @@ class MalleableRunner:
         if target == self.current and not force:
             return state
         with span("dmr.resize", step=step, procs=target):
-            compiled = COMPILE.seconds
+            compiling = COMPILE.seconds
             new_mesh = self._mesh_for(target)
             new_shardings = self.app.state_shardings(new_mesh)
             state, stats, per_pattern = self._redistribute(
@@ -296,12 +296,13 @@ class MalleableRunner:
                 t0 = time.perf_counter()
                 self._step_fn(target)  # cached across resizes; jit is lazy
                 recompile = time.perf_counter() - t0
+            compiled = COMPILE.seconds - compiling
         kind = action.kind if target != self.current else "migrate"
         event = ResizeEvent(
             step=step, action=kind, from_procs=self.current,
             to_procs=target, transfer=stats, recompile_s=recompile,
-            per_pattern=per_pattern)
-        self._compiling = (event, compiled)
+            per_pattern=per_pattern, compile_s=compiled)
+        self._compiling = event
         self.events.append(event)
         if self.event_listener is not None:
             self.event_listener(event)
@@ -311,12 +312,12 @@ class MalleableRunner:
 
     # ------------------------------------------------------------------
     def step(self, state, step: int, *args):
+        event, self._compiling = self._compiling, None
         with span("dmr.step", step=step):
+            compiling = COMPILE.seconds
             out = self._step_fn(self.current)(state, step, *args)
-        if self._compiling is not None:
-            event, compiled = self._compiling
-            self._compiling = None
-            event.compile_s = COMPILE.seconds - compiled
+        if event is not None:
+            event.compile_s += COMPILE.seconds - compiling
         return out
 
     # fault tolerance: forced shrink onto survivors (DESIGN.md §6)

@@ -121,6 +121,47 @@ def test_resize_spans_nest_and_carry_the_step(resized):
         assert not [s for s in steps if a <= s[1] < b]
 
 
+def _toy_runner(scale: float):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import dmr
+    app = dmr.App(name=f"toy{scale}")
+
+    @app.shardings
+    def shardings(mesh):
+        return {"x": NamedSharding(mesh, P())}
+
+    @app.init
+    def init(mesh):
+        return jax.device_put({"x": jnp.arange(8.0)}, shardings(mesh))
+
+    @app.step
+    def step(mesh):
+        f = jax.jit(lambda s: {"x": jnp.cos(s["x"]) * scale})
+        return lambda state, i: (f(state), None)
+    return dmr.MalleableRunner(app, dmr.set_parameters(1, 1, 1), rms={})
+
+
+def test_resize_compile_s_leaves_out_another_runners_compile():
+    from repro.core.policy import Action
+    a, b = _toy_runner(2.0), _toy_runner(3.0)
+    state_a = a.init()
+    before = COMPILE.seconds
+    state_a = a.apply_resize(state_a, 0, Action("shrink", 1), force=True)
+    in_resize = COMPILE.seconds - before
+    before = COMPILE.seconds          # runner b compiles its first step
+    b.step(b.init(), 0)
+    in_b = COMPILE.seconds - before
+    before = COMPILE.seconds
+    a.step(state_a, 0)                # a's first step on the new mesh
+    in_step = COMPILE.seconds - before
+    (event,) = a.events
+    assert event.action == "migrate"
+    assert in_b > 0 and in_step > 0
+    assert event.compile_s == pytest.approx(in_resize + in_step, abs=1e-12)
+    a.step(state_a, 1)                # later steps add nothing
+    assert event.compile_s == pytest.approx(in_resize + in_step, abs=1e-12)
+
+
 def _op_names(hlo: str):
     return re.findall(r'op_name="([^"]*)"', hlo)
 
