@@ -287,8 +287,18 @@ def _attend_cache(q, k, v, valid, cfg: ArchConfig):
     return o.reshape(B, 1, H, hd)
 
 
+def _seq_rows(t, layer, b):
+    """Sequence ``b``'s rows (1, S, Hkv, hd) of a per-layer cache leaf, or
+    of layer ``layer`` of a stacked one, sliced where they lie."""
+    if layer is None:
+        return jax.lax.dynamic_slice_in_dim(t, b, 1, 0)
+    return jax.lax.dynamic_slice(t, (layer, b) + (0,) * (t.ndim - 2),
+                                 (1, 1) + t.shape[2:])[0]
+
+
 def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
-                      cross: bool = False, row_stable: bool = False):
+                      layer=None, cross: bool = False,
+                      row_stable: bool = False):
     """One-token decode against a KV cache.
 
     cache: {"k","v"}: (B, S_cache, Hkv, hd).  ``cache_index`` is the absolute
@@ -296,11 +306,20 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     ``window`` slots.  ``x`` may carry padding rows after the cache's B
     sequences (``decode_step(row_stable=True)``); they stay out of the cache.
 
+    ``layer`` (a traced int32 scalar, ``0 <= layer < L``), when given, says
+    the leaves are the whole stack (L, B, S_cache, Hkv, hd) that a layer
+    scan carries: the new token's K and V rows are written into it in place
+    at (``layer``, :, slot), one ``dynamic_update_slice`` each, attention
+    reads layer ``layer``'s rows straight from the stack, and the stack
+    comes back with only those rows changed.  Without it (the cross cache,
+    the hybrid's shared cache) the leaves are one layer's.
+
     ``row_stable`` reads the cache one sequence at a time, so that a
     sequence's scores do not depend on how many others the program holds.
     """
     dt = jnp.dtype(cfg.dtype)
-    B, rows = cache["k"].shape[0], x.shape[0]
+    stacked = layer is not None
+    B, rows = cache["k"].shape[int(stacked)], x.shape[0]
     pos = jnp.full((rows, 1), cache_index)
     q, k_new, v_new = _project_qkv(params, x, cfg, pos, rope=not cross)
     if row_stable:
@@ -312,13 +331,16 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
         k, v = cache["k"], cache["v"]
         valid = jnp.ones((k.shape[1],), bool)
     else:
-        S = cache["k"].shape[1]
+        S = cache["k"].shape[-3]
         slot = jnp.mod(cache_index, S) if cfg.attention == "swa" else cache_index
+        at = (0, slot, 0, 0)
+        if stacked:
+            k_new, v_new, at = k_new[None], v_new[None], (layer,) + at
         with jax.named_scope("kv_write"):
             k = jax.lax.dynamic_update_slice(
-                cache["k"], k_new.astype(cache["k"].dtype), (0, slot, 0, 0))
+                cache["k"], k_new.astype(cache["k"].dtype), at)
             v = jax.lax.dynamic_update_slice(
-                cache["v"], v_new.astype(cache["v"].dtype), (0, slot, 0, 0))
+                cache["v"], v_new.astype(cache["v"].dtype), at)
         cache = {"k": k, "v": v}
         with jax.named_scope("attend"):        # the mask is attention's
             kpos = jnp.arange(S)
@@ -333,9 +355,17 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     # the split-KV psum combine.
     with jax.named_scope("attend"):
         if row_stable:
-            o = jax.lax.map(lambda a: _attend_cache(
-                *(t[None] for t in a), valid, cfg)[0], (q, k, v))
+            # the sequence index rides in the carry: scanned from an array,
+            # each step would first load it from memory
+            def one(b, qb):
+                return b + 1, _attend_cache(
+                    qb[None], _seq_rows(k, layer, b),
+                    _seq_rows(v, layer, b), valid, cfg)[0]
+            _, o = jax.lax.scan(one, jnp.int32(0), q)
         else:
+            if stacked:
+                k, v = (jax.lax.dynamic_index_in_dim(t, layer, 0, False)
+                        for t in (k, v))
             o = _attend_cache(q, k, v, valid, cfg)
     with jax.named_scope("attn_out"):
         o = jnp.pad(o, ((0, rows - B), (0, 0), (0, 0), (0, 0)))

@@ -51,11 +51,14 @@ def decoder_block_apply(params, x, cfg: ArchConfig, *, positions,
 
 
 def decoder_block_decode(params, x, cfg: ArchConfig, cache, *, cache_index,
-                         cross_cache=None, row_stable: bool = False):
-    """One-token decode. cache: {"k","v"}; cross_cache: precomputed enc K/V."""
+                         layer=None, cross_cache=None,
+                         row_stable: bool = False):
+    """One-token decode. cache: {"k","v"}, the stack of every layer's where
+    ``layer`` is given (``attention.decode_attn_apply``); cross_cache:
+    precomputed enc K/V of this layer."""
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     a, cache = attn.decode_attn_apply(params["attn"], h, cfg, cache,
-                                      cache_index=cache_index,
+                                      cache_index=cache_index, layer=layer,
                                       row_stable=row_stable)
     x = x + a
     if cross_cache is not None:

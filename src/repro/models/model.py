@@ -221,6 +221,12 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
                 row_stable: bool = False):
     """One-token decode. tokens: (B, 1) int32. Returns (logits, new cache).
 
+    Attention decoders carry the stacked KV cache (L, B, S, Hkv, hd) through
+    the layer scan: layer ``l`` writes the new token's K and V rows into it
+    in place at ``l`` and reads its attention rows from it there
+    (``attention.decode_attn_apply`` with ``layer``), so a donated cache is
+    updated where it lies, with no layer slice or whole-cache copy.
+
     ``row_stable`` computes each sequence the same way whatever ``B`` is:
     the dense layers run on the batch padded to a multiple of ``ROW_TILE``
     rows, and attention reads the cache one sequence at a time. A replica
@@ -280,28 +286,24 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
         }
 
     else:
-        cross = cache.get("cross") if cfg.is_encdec else None
-        scanned = (params["layers"], cache["layers"]) if cross is None else \
-            (params["layers"], cache["layers"], cross)
+        # the stacked self-attention cache is the scan's carry, written one
+        # row per layer in place; the read-only cross cache is scanned
+        xs = (params["layers"], jnp.arange(cfg.num_layers))
+        if cfg.is_encdec:
+            xs += (cache["cross"],)
 
-        def body(h, sc):
-            if cross is None:
-                lp, c = sc
-                h, c = blocks.decoder_block_decode(lp, h, cfg, c,
-                                                   cache_index=cache_index,
-                                                   row_stable=row_stable)
-            else:
-                lp, c, cc = sc
-                h, c = blocks.decoder_block_decode(lp, h, cfg, c,
-                                                   cache_index=cache_index,
-                                                   cross_cache=cc,
-                                                   row_stable=row_stable)
-            return h, c
+        def body(carry, sc):
+            h, kv = carry
+            lp, layer, *cross = sc
+            h, kv = blocks.decoder_block_decode(
+                lp, h, cfg, kv, cache_index=cache_index, layer=layer,
+                cross_cache=cross[0] if cross else None,
+                row_stable=row_stable)
+            return (h, kv), None
 
         with jax.named_scope("layers"):
-            x, new_kv = jax.lax.scan(body, x, scanned)
-        cache = dict(cache)
-        cache["layers"] = new_kv
+            (x, kv), _ = jax.lax.scan(body, (x, cache["layers"]), xs)
+        cache = {**cache, "layers": kv}
 
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg)

@@ -94,6 +94,58 @@ def test_row_stable_decode(arch):
     np.testing.assert_array_equal(split, stable)
 
 
+def _reference_decode(params, cfg, tokens, cache, cache_index, row_stable):
+    """``decode_step`` as a Python loop over the layers: each layer's cache
+    is sliced out of the stack, gets its new row, and is put back."""
+    from repro.models import blocks
+    from repro.models.layers import embed, rmsnorm, unembed
+    B = tokens.shape[0]
+    if row_stable:
+        tokens = jnp.pad(tokens, ((0, -B % M.ROW_TILE), (0, 0)))
+    x, kv = embed(params["embed"], tokens, cfg), cache["layers"]
+    for l in range(cfg.num_layers):
+        at = lambda t: t[l]                                 # noqa: E731
+        x, c = blocks.decoder_block_decode(
+            jax.tree.map(at, params["layers"]), x, cfg, jax.tree.map(at, kv),
+            cache_index=cache_index, row_stable=row_stable,
+            cross_cache=jax.tree.map(at, cache["cross"])
+            if cfg.is_encdec else None)
+        kv = jax.tree.map(lambda t, n: t.at[l].set(n), kv, c)
+    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
+    return unembed(params["embed"], x, cfg)[:B], {**cache, "layers": kv}
+
+
+@pytest.mark.parametrize("arch,row_stable", [
+    ("granite-3-2b", True), ("granite-3-2b", False),
+    ("mixtral-8x7b", False),              # SWA (window 16) and MoE
+    ("qwen3-moe-235b-a22b", False),
+    ("seamless-m4t-medium", False), ("seamless-m4t-medium", True)])
+def test_decode_step_matches_per_layer_reference(arch, row_stable):
+    """The layer scan that carries the stacked cache and writes one row per
+    layer in place gives the per-layer reference's logits, tokens and cache
+    bit for bit: 32 steps fill a 32-deep cache, or wrap mixtral's 16-slot
+    rolling buffer twice."""
+    cfg = get_config(arch + "-smoke")
+    params = init_state(cfg, OPT, 0).params
+    cache = M.init_cache(cfg, 3, 32, enc_len=16)
+    assert cfg.attention != "swa" or cache["layers"]["k"].shape[2] == 16
+    step = jax.jit(lambda p, t, c, i: M.decode_step(p, cfg, t, c, i,
+                                                    row_stable=row_stable))
+    ref = jax.jit(lambda p, t, c, i: _reference_decode(p, cfg, t, c, i,
+                                                       row_stable))
+    tok = ref_tok = jnp.asarray([3, 1, 4], jnp.int32)[:, None]
+    ref_cache = cache
+    for i in range(32):
+        logits, cache = step(params, tok, cache, jnp.int32(i))
+        ref_logits, ref_cache = ref(params, ref_tok, ref_cache, jnp.int32(i))
+        np.testing.assert_array_equal(logits, ref_logits)
+        tok = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1)[:, None]
+        ref_tok = jnp.argmax(ref_logits[:, -1, :cfg.vocab_size], -1)[:, None]
+        np.testing.assert_array_equal(tok, ref_tok)
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_row_stable_decode_refuses_shared_state():
     cfg = get_config("mamba2-370m-smoke")
     assert not M.row_stable_decode(cfg)

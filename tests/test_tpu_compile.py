@@ -65,9 +65,9 @@ def test_repack_compiles(one_chip):
         _spec(one_chip, (64,), jnp.int32)))
 
 
-def _matmul_shapes(cfg, sharding, rows):
-    """Output shapes of the matmuls (lowered to convolutions) in the chip's
-    program for a row-stable decode step over ``rows`` sequences."""
+def _serve_step(cfg, sharding, rows, cache_len, **jit_kw):
+    """The chip's compiled row-stable decode step over ``rows`` sequences
+    and a ``cache_len``-deep cache."""
     from repro.models import model as M
     from repro.models.train import make_serve_step
 
@@ -77,10 +77,16 @@ def _matmul_shapes(cfg, sharding, rows):
     params = jax.tree.map(lambda a: _spec(sharding, a.shape),
                           M.abstract_params(cfg))
     cache = jax.tree.map(spec, jax.eval_shape(
-        lambda: M.init_cache(cfg, rows, 128)))
-    text = jax.jit(make_serve_step(cfg, row_stable=True)).lower(
+        lambda: M.init_cache(cfg, rows, cache_len)))
+    return jax.jit(make_serve_step(cfg, row_stable=True), **jit_kw).lower(
         params, cache, _spec(sharding, (rows, 1), jnp.int32),
-        _spec(sharding, (), jnp.int32)).compile().as_text()
+        _spec(sharding, (), jnp.int32)).compile()
+
+
+def _matmul_shapes(cfg, sharding, rows):
+    """Output shapes of the matmuls (lowered to convolutions) in the chip's
+    program for a row-stable decode step over ``rows`` sequences."""
+    text = _serve_step(cfg, sharding, rows, 128).as_text()
     return sorted(re.findall(r"= (\w+\[[\d,]*\])\S* convolution\(", text))
 
 
@@ -93,3 +99,32 @@ def test_row_stable_decode_runs_the_same_matmuls(one_chip):
     cfg = get_config("granite-3-2b")
     shapes = {rows: _matmul_shapes(cfg, one_chip, rows) for rows in (8, 4, 2)}
     assert shapes[8] and shapes[4] == shapes[8] and shapes[2] == shapes[8]
+
+
+def test_decode_step_writes_the_cache_in_place(one_chip):
+    """granite-3-2b's row-stable step at batch 256 and cache 256, the cache
+    donated: the stacked (40, 256, 256, 8, 64) K and V caches are carried
+    through the layer scan and only the new row of each layer is written.
+    No whole-cache or layer-sized cache copy or slice is left, the
+    temporaries are far below one cache (2.5 GiB each), and the output
+    aliases the donated cache."""
+    from repro.configs import get_config
+    cfg = get_config("granite-3-2b")
+    compiled = _serve_step(cfg, one_chip, 256, 256, donate_argnums=(1,))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256 * 2**20
+    cache_bytes = 2 * 40 * 256 * 256 * 8 * 64 * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+
+    text = compiled.as_text()
+    shapes = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))
+    cache_sized = re.findall(
+        r"= (bf16\[(?:\d+,)?256,256,8,64\])\S* ([\w-]+)\(([^)]*)\)", text)
+    assert cache_sized
+    for shape, op, operands in cache_sized:
+        assert op in ("parameter", "get-tuple-element",
+                      "dynamic-update-slice"), (shape, op)
+        if op == "dynamic-update-slice":
+            update = shapes[operands.split(", ")[1]]
+            assert shape == "bf16[40,256,256,8,64]"
+            assert update == "bf16[1,256,1,8,64]", update
