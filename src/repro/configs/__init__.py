@@ -1,4 +1,4 @@
-"""Config registry: the 10 assigned architectures + reduced smoke variants."""
+"""Config registry: the assigned architectures + reduced smoke variants."""
 from __future__ import annotations
 
 import importlib
@@ -30,6 +30,7 @@ _ARCH_MODULES = {
     "mixtral-8x7b": "mixtral_8x7b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
     "mamba2-370m": "mamba2_370m",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -33,6 +33,8 @@ class SSMConfig:
     expand: int = 2               # d_inner = expand * d_model
     conv_width: int = 4
     chunk_size: int = 256         # SSD chunk length
+    conv_bias: bool = False       # a bias on each channel of the conv
+    state_dtype: str = "float32"  # the decode cache's SSM state
 
 
 @dataclass(frozen=True)
@@ -70,6 +72,7 @@ class ArchConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"   # rope | nope (no positions at all)
 
     # -- MoE / SSM / hybrid ---------------------------------------------
     moe: Optional[MoEConfig] = None
@@ -77,6 +80,10 @@ class ArchConfig:
     # hybrid (zamba2): one *shared-weight* attention block applied after
     # every ``shared_attention_every`` SSM layers.
     shared_attention_every: int = 0
+    # interleaved hybrid (granite-4.0-h): the mixer of each layer of one
+    # period, "mamba" or "attention", repeated over the depth; every layer
+    # has its own mixer and an MLP.
+    mixer_period: Tuple[str, ...] = ()
 
     # -- encoder/decoder --------------------------------------------------
     encoder_layers: int = 0       # >0 -> encoder-decoder (cross-attention)
@@ -89,6 +96,12 @@ class ArchConfig:
     param_dtype: str = "float32"  # master weight dtype
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # Granite's multipliers: of the embedding, of each residual branch, the
+    # attention scale (None: head_dim ** -0.5) and the logits' divisor
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     # -- training ----------------------------------------------------------
     remat: bool = True            # activation checkpointing over the layer scan
@@ -101,6 +114,22 @@ class ArchConfig:
     def __post_init__(self):
         if self.attention != "none" and self.num_heads and self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(f"{self.name}: position_embedding "
+                             f"{self.position_embedding!r}")
+        if self.mixer_period:
+            period = self.mixer_period
+            if self.ssm is None or self.attention == "none" \
+                    or self.shared_attention_every:
+                raise ValueError(f"{self.name}: an interleaved hybrid needs "
+                                 f"an SSM, attention and no shared block")
+            if not set(period) <= {"mamba", "attention"} \
+                    or self.num_layers % len(period):
+                raise ValueError(f"{self.name}: {self.num_layers} layers "
+                                 f"are no whole number of periods {period}")
+        elif self.residual_multiplier != 1.0:
+            raise ValueError(f"{self.name}: only the interleaved hybrid's "
+                             f"layers run a residual_multiplier")
 
     # convenience ------------------------------------------------------
     @property
@@ -115,6 +144,23 @@ class ArchConfig:
     @property
     def is_hybrid(self) -> bool:
         return self.ssm is not None and self.shared_attention_every > 0
+
+    @property
+    def is_interleaved(self) -> bool:
+        return bool(self.mixer_period)
+
+    @property
+    def layer_types(self) -> Tuple[str, ...]:
+        """The mixer of every layer of an interleaved hybrid."""
+        if not self.mixer_period:
+            return ()
+        return self.mixer_period * (self.num_layers // len(self.mixer_period))
+
+    @property
+    def attention_scale(self) -> float:
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.head_dim ** -0.5
 
     @property
     def is_encdec(self) -> bool:
@@ -210,7 +256,11 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
                               d_ff=64, capacity_factor=2.0)
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(state_size=16, head_dim=16, expand=2,
-                              conv_width=4, chunk_size=32)
+                              conv_width=4, chunk_size=32,
+                              conv_bias=cfg.ssm.conv_bias)
+    if cfg.mixer_period:
+        # one layer of each kind, in the order the period first has them
+        kw["mixer_period"] = tuple(dict.fromkeys(cfg.mixer_period))
     if cfg.shared_attention_every:
         kw["shared_attention_every"] = 2
         kw.update(num_heads=4, num_kv_heads=4, head_dim=16)

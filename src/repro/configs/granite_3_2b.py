@@ -18,4 +18,9 @@ CONFIG = ArchConfig(
     vocab_size=49155,
     attention="full",
     tie_embeddings=True,
+    # Granite's multipliers are not run: the block is a plain dense decoder
+    embedding_multiplier=1.0,
+    residual_multiplier=1.0,
+    attention_multiplier=None,   # head_dim ** -0.5
+    logits_scaling=1.0,
 )

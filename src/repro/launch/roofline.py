@@ -77,6 +77,11 @@ def param_counts(cfg: ArchConfig) -> Dict[str, float]:
         n_layers = cfg.num_layers
         total = emb + n_layers * ssm
         active = total
+    elif cfg.is_interleaved:
+        n_attn = cfg.layer_types.count("attention")
+        total = active = emb + (cfg.num_layers - n_attn) * ssm \
+            + n_attn * per_layer_attn + cfg.num_layers * mlp
+        layer_total = layer_active = ssm + mlp
     elif cfg.is_hybrid:
         groups = cfg.num_layers // cfg.shared_attention_every
         shared = per_layer_attn + mlp
@@ -122,6 +127,8 @@ def model_flops(cfg: ArchConfig, shape: ShapeConfig) -> float:
 
     if cfg.is_hybrid:
         attn_layers = cfg.num_layers // cfg.shared_attention_every
+    elif cfg.is_interleaved:
+        attn_layers = cfg.layer_types.count("attention")
     elif cfg.attention == "none":
         attn_layers = 0
     else:

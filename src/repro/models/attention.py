@@ -61,7 +61,7 @@ def _project_qkv(params, x, cfg: ArchConfig, positions, kv_x=None,
     if cfg.qk_norm:
         q = rmsnorm({"scale": params["q_norm"]}, q, cfg.norm_eps)
         k = rmsnorm({"scale": params["k_norm"]}, k, cfg.norm_eps)
-    if rope:
+    if rope and cfg.position_embedding == "rope":
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions if kv_x is None else
                        jnp.arange(kv_in.shape[1])[None, :], cfg.rope_theta)
@@ -99,8 +99,9 @@ def repeat_kv(k, num_heads: int):
 @jax.named_scope("attend")
 def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
                       chunk_q: int = 1024, chunk_k: int = 1024,
-                      q_offset: int = 0):
-    """q: (B,Sq,H,hd); k,v: (B,Sk,Hkv,hd). Online softmax over KV chunks.
+                      q_offset: int = 0, scale: Optional[float] = None):
+    """q: (B,Sq,H,hd); k,v: (B,Sk,Hkv,hd). Online softmax over KV chunks;
+    ``scale`` multiplies the scores (default ``hd ** -0.5``).
 
     Memory is bounded by (B, H, chunk_q, chunk_k) score blocks regardless of
     sequence length — required for the 32k prefill cells. Head dim stays flat
@@ -115,7 +116,8 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     ck = min(chunk_k, Sk)
     assert Sq % cq == 0 and Sk % ck == 0, (Sq, cq, Sk, ck)
     nq, nk = Sq // cq, Sk // ck
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
 
     # pin the chunk stacks to (batch, -, -, heads, -) BEFORE the loops:
     # otherwise XLA spreads the model axis over the chunk dims and every
@@ -200,7 +202,8 @@ def attn_apply(params, x, cfg: ArchConfig, *, positions, kv_x=None,
     q, k, v = _project_qkv(params, x, cfg, positions, kv_x=kv_x, rope=rope)
     window = cfg.window if cfg.attention == "swa" else 0
     out = chunked_attention(q, k, v, causal=causal, window=window,
-                            chunk_q=cfg.attn_chunk_q, chunk_k=cfg.attn_chunk_k)
+                            chunk_q=cfg.attn_chunk_q, chunk_k=cfg.attn_chunk_k,
+                            scale=cfg.attention_scale)
     with jax.named_scope("attn_out"):
         return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
 
@@ -265,7 +268,8 @@ def _attn_apply_seq_shardmap(params, x, cfg: ArchConfig, mesh, rules, *,
             out = chunked_attention(
                 q, k, v, causal=causal, window=window,
                 chunk_q=min(cfg.attn_chunk_q, S_loc),
-                chunk_k=cfg.attn_chunk_k, q_offset=offset)
+                chunk_k=cfg.attn_chunk_k, q_offset=offset,
+                scale=cfg.attention_scale)
             with jax.named_scope("attn_out"):
                 return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
@@ -280,7 +284,7 @@ def _attend_cache(q, k, v, valid, cfg: ArchConfig):
     Hkv = k.shape[2]
     qg = q.reshape(B, 1, Hkv, H // Hkv, hd)
     s = jnp.einsum("bqngd,bsnd->bngqs", qg, k,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+                   preferred_element_type=jnp.float32) * cfg.attention_scale
     s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(jnp.dtype(cfg.dtype))
     o = jnp.einsum("bngqs,bsnd->bqngd", p, v)

@@ -91,10 +91,71 @@ def ssm_block_apply(params, x, cfg: ArchConfig):
     return constrain(y, "act_batch", "act_seq_blk", "act_embed")
 
 
-def ssm_block_decode(params, x, cfg: ArchConfig, cache):
+def ssm_block_decode(params, x, cfg: ArchConfig, cache, cache_index):
     h = rmsnorm(params["ln"], x, cfg.norm_eps)
-    y, cache = ssm_mod.ssm_decode_step(params["ssm"], h, cfg, cache)
+    y, cache = ssm_mod.ssm_decode_step(params["ssm"], h, cfg, cache,
+                                       cache_index)
     return x + y, cache
+
+
+# ----------------------------------------------------------------------
+# Interleaved hybrid layer (granite-4.0-h): x += r·mixer(norm(x));
+# x += r·mlp(norm(x)), the mixer Mamba-2 or attention
+# ----------------------------------------------------------------------
+
+def mixer_schema(cfg: ArchConfig, kind: str):
+    """One layer's mixer and its norm: ``kind`` "mamba" or "attention"."""
+    if kind == "mamba":
+        return ssm_block_schema(cfg)
+    return {"ln": rmsnorm_schema(cfg.d_model, cfg),
+            "attn": attn.attention_schema(cfg)}
+
+
+def mlp_block_schema(cfg: ArchConfig):
+    return {"ln": rmsnorm_schema(cfg.d_model, cfg), "mlp": mlp_schema(cfg)}
+
+
+def _residual(x, y, cfg: ArchConfig):
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
+def interleaved_layer_apply(mixer, mlp_params, x, cfg: ArchConfig, kind: str,
+                            *, positions):
+    from repro.parallel.context import constrain
+    h = rmsnorm(mixer["ln"], x, cfg.norm_eps)
+    if kind == "mamba":
+        y = ssm_mod.ssm_apply(mixer["ssm"], h, cfg)
+    else:
+        y = attn.attn_apply(mixer["attn"], h, cfg, positions=positions,
+                            causal=True)
+    x = constrain(_residual(x, y, cfg), "act_batch", "act_seq_blk",
+                  "act_embed")
+    h = rmsnorm(mlp_params["ln"], x, cfg.norm_eps)
+    return constrain(_residual(x, mlp(mlp_params["mlp"], h, cfg), cfg),
+                     "act_batch", "act_seq_blk", "act_embed")
+
+
+def interleaved_layer_decode(mixer, mlp_params, x, cfg: ArchConfig,
+                             kind: str, cache, *, layer, cache_index):
+    """One-token decode of one layer. ``cache`` is the stack of the
+    layers of ``kind`` (the SSM state and conv windows, or K and V) and
+    ``layer`` the index of this one in it; its rows are written in place.
+    Attention reads the stack one sequence at a time (``decode_attn_apply``'s
+    row-stable read): read as a batch, the layer's K and V are sliced out
+    and the whole stack laid out anew each step."""
+    h = rmsnorm(mixer["ln"], x, cfg.norm_eps)
+    if kind == "mamba":
+        y, cache = ssm_mod.ssm_decode_step(mixer["ssm"], h, cfg, cache,
+                                           cache_index, layer=layer)
+    else:
+        y, cache = attn.decode_attn_apply(mixer["attn"], h, cfg, cache,
+                                          cache_index=cache_index,
+                                          layer=layer, row_stable=True)
+    x = _residual(x, y, cfg)
+    h = rmsnorm(mlp_params["ln"], x, cfg.norm_eps)
+    return _residual(x, mlp(mlp_params["mlp"], h, cfg), cfg), cache
 
 
 # ----------------------------------------------------------------------

@@ -100,7 +100,10 @@ def embed_schema(cfg: ArchConfig):
 @jax.named_scope("embed")
 def embed(params, tokens, cfg: ArchConfig):
     table = params["embedding"].astype(jnp.dtype(cfg.dtype))
-    return jnp.take(table, tokens, axis=0)
+    x = jnp.take(table, tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
 
 
 @jax.named_scope("unembed")
@@ -112,4 +115,6 @@ def unembed(params, x, cfg: ArchConfig):
         logits = jnp.einsum("...d,vd->...v", x, w)
     else:
         logits = jnp.einsum("...d,dv->...v", x, params["unembed"].astype(dt))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return constrain(logits, "act_batch", "act_seq", "act_vocab")

@@ -42,6 +42,13 @@ def model_schema(cfg: ArchConfig):
         }
         assert cfg.num_layers % every == 0, (cfg.num_layers, every)
         del groups
+    elif cfg.is_interleaved:
+        kinds = cfg.layer_types
+        s["layers"] = {
+            kind: P.stack(blocks.mixer_schema(cfg, kind), kinds.count(kind))
+            for kind in ("mamba", "attention")}
+        s["layers"]["mlp"] = P.stack(blocks.mlp_block_schema(cfg),
+                                     cfg.num_layers)
     else:
         s["layers"] = P.stack(
             blocks.decoder_block_schema(cfg, cross=cfg.is_encdec),
@@ -84,8 +91,62 @@ def _scan_layers(layer_params, x, body, cfg: ArchConfig):
     return x, jnp.sum(auxs)
 
 
+def _period_runs(cfg: ArchConfig):
+    """The mixer period as runs of one kind: ``(kind, first index among
+    the period's layers of that kind, first layer, length)``."""
+    runs, seen = [], {"mamba": 0, "attention": 0}
+    for i, kind in enumerate(cfg.mixer_period):
+        if runs and runs[-1][0] == kind:
+            k, first, layer, n = runs[-1]
+            runs[-1] = (k, first, layer, n + 1)
+        else:
+            runs.append((kind, seen[kind], i, 1))
+        seen[kind] += 1
+    return runs, seen
+
+
+def _interleaved_scan(cfg: ArchConfig, carry, layer_fn, remat: bool = False):
+    """The interleaved hybrid's layers, scanned period by period: an outer
+    scan over the periods and in each one a scan per run of layers of one
+    kind (granite-4.0-h: 5 Mamba, 1 attention, 4 Mamba).
+    ``layer_fn(carry, kind, index, layer)`` applies layer ``layer``, the
+    ``index``-th of its kind, both traced int32 scalars."""
+    runs, per_kind = _period_runs(cfg)
+    periods = cfg.num_layers // len(cfg.mixer_period)
+
+    def period(c, p):
+        for kind, first, layer0, n in runs:
+            def body(cc, i, kind=kind, first=first, layer0=layer0):
+                return layer_fn(cc, kind, p * per_kind[kind] + first + i,
+                                p * len(cfg.mixer_period) + layer0 + i), None
+            if remat:
+                body = jax.checkpoint(body)
+            c, _ = jax.lax.scan(body, c, jnp.arange(n))
+        return c, None
+
+    carry, _ = jax.lax.scan(period, carry, jnp.arange(periods))
+    return carry
+
+
+def _layer_params(stack, index):
+    return jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, index, 0, keepdims=False),
+        stack)
+
+
 def _trunk(params, x, cfg: ArchConfig, positions, enc_out=None):
     """Hidden-state trunk shared by train and prefill. Returns (x, aux)."""
+    if cfg.is_interleaved:
+        layers = params["layers"]
+
+        def layer(h, kind, index, l):
+            return blocks.interleaved_layer_apply(
+                _layer_params(layers[kind], index),
+                _layer_params(layers["mlp"], l), h, cfg, kind,
+                positions=positions)
+        return _interleaved_scan(cfg, x, layer, remat=cfg.remat), \
+            jnp.float32(0.0)
+
     if cfg.is_ssm:
         def body(h, lp):
             return blocks.ssm_block_apply(lp, h, cfg), jnp.float32(0.0)
@@ -194,6 +255,18 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
             "shared_kv": jax.tree.map(
                 lambda t: jnp.broadcast_to(t, (groups,) + t.shape), kv_one),
         }
+    if cfg.is_interleaved:
+        # every Mamba layer's state and conv windows, and every attention
+        # layer's K and V, stacked by kind: the decode scan carries both
+        kinds = cfg.layer_types
+        ssm_one = ssm_mod.init_ssm_cache(cfg, batch)
+        kv_one = attn_mod.init_kv_cache(cfg, batch, seq_len)
+        return {"mamba": jax.tree.map(
+                    lambda t: jnp.broadcast_to(
+                        t, (kinds.count("mamba"),) + t.shape), ssm_one),
+                "attention": jax.tree.map(
+                    lambda t: jnp.broadcast_to(
+                        t, (kinds.count("attention"),) + t.shape), kv_one)}
     kv_one = attn_mod.init_kv_cache(cfg, batch, seq_len)
     cache = {"layers": jax.tree.map(
         lambda t: jnp.broadcast_to(t, (L,) + t.shape), kv_one)}
@@ -212,9 +285,20 @@ ROW_TILE = 8
 
 def row_stable_decode(cfg: ArchConfig) -> bool:
     """Whether ``decode_step(row_stable=True)`` covers ``cfg``: attention
-    decoders whose sequences never meet (no SSM state, no expert capacity
-    shared between tokens)."""
-    return not (cfg.is_ssm or cfg.is_hybrid or cfg.is_moe)
+    decoders whose sequences never meet (no expert capacity shared between
+    tokens). Models with SSM state (Mamba-2, the shared-attention hybrid,
+    the interleaved hybrid) are not covered yet."""
+    return not (cfg.is_ssm or cfg.is_hybrid or cfg.is_interleaved
+                or cfg.is_moe)
+
+
+def _decoder_kind(cfg: ArchConfig) -> str:
+    """What keeps ``cfg`` out of ``row_stable_decode``."""
+    if cfg.is_interleaved:
+        return "an interleaved Mamba-2/attention hybrid"
+    if cfg.is_hybrid:
+        return "a shared-attention hybrid"
+    return "a Mamba-2 model" if cfg.is_ssm else "a mixture of experts"
 
 
 def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
@@ -225,7 +309,10 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
     the layer scan: layer ``l`` writes the new token's K and V rows into it
     in place at ``l`` and reads its attention rows from it there
     (``attention.decode_attn_apply`` with ``layer``), so a donated cache is
-    updated where it lies, with no layer slice or whole-cache copy.
+    updated where it lies, with no layer slice or whole-cache copy. The
+    interleaved hybrid's scan carries its Mamba layers' stacked state and
+    conv windows and its attention layers' stacked KV the same way, each
+    layer updating its own slice (``blocks.interleaved_layer_decode``).
 
     ``row_stable`` computes each sequence the same way whatever ``B`` is:
     the dense layers run on the batch padded to a multiple of ``ROW_TILE``
@@ -237,19 +324,36 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
     if row_stable:
         if not row_stable_decode(cfg):
             raise NotImplementedError(
-                f"row-stable decode covers attention decoders, not {cfg.name}")
+                f"row-stable decode covers attention decoders, not "
+                f"{cfg.name} ({_decoder_kind(cfg)})")
         tokens = jnp.pad(tokens, ((0, -B % ROW_TILE), (0, 0)))
     x = embed(params["embed"], tokens, cfg)
 
     if cfg.is_ssm:
         def body(h, scanned):
             lp, c = scanned
-            h, c = blocks.ssm_block_decode(lp, h, cfg, c)
+            h, c = blocks.ssm_block_decode(lp, h, cfg, c, cache_index)
             return h, c
         with jax.named_scope("layers"):
             x, new_cache = jax.lax.scan(body, x,
                                         (params["layers"], cache["layers"]))
         cache = {"layers": new_cache}
+
+    elif cfg.is_interleaved:
+        # the stacked SSM state, conv windows and KV are the scan's carry;
+        # each layer writes its own slice in place
+        layers = params["layers"]
+
+        def layer(carry, kind, index, l):
+            h, c = carry
+            h, own = blocks.interleaved_layer_decode(
+                _layer_params(layers[kind], index),
+                _layer_params(layers["mlp"], l), h, cfg, kind, c[kind],
+                layer=index, cache_index=cache_index)
+            return h, {**c, kind: own}
+
+        with jax.named_scope("layers"):
+            x, cache = _interleaved_scan(cfg, (x, cache), layer)
 
     elif cfg.is_hybrid:
         every = cfg.shared_attention_every
@@ -265,7 +369,8 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
 
             def inner(hh, sc):
                 lp, c = sc
-                hh, c = blocks.ssm_block_decode(lp, hh, cfg, c)
+                hh, c = blocks.ssm_block_decode(lp, hh, cfg, c,
+                                                cache_index)
                 return hh, c
             h, gc = jax.lax.scan(inner, h, (glp, gc))
             hn = rmsnorm(shared["ln1"], h, cfg.norm_eps)

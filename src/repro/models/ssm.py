@@ -23,7 +23,7 @@ def ssm_schema(cfg: ArchConfig):
     h = cfg.ssm_num_heads
     w = cfg.ssm.conv_width
     pd = cfg.param_dtype
-    return {
+    s = {
         "w_z": ParamDef((d, di), ("embed", "ssm_inner"), dtype=pd),
         "w_x": ParamDef((d, di), ("embed", "ssm_inner"), dtype=pd),
         "w_B": ParamDef((d, n), ("embed", "ssm_state"), dtype=pd),
@@ -39,10 +39,19 @@ def ssm_schema(cfg: ArchConfig):
         "w_out": ParamDef((di, d), ("ssm_inner", "embed"), dtype=pd,
                           init="scaled_normal"),
     }
+    if cfg.ssm.conv_bias:
+        s["conv_x_bias"] = ParamDef((di,), ("ssm_inner",), dtype=pd,
+                                    init="zeros")
+        s["conv_B_bias"] = ParamDef((n,), ("ssm_state",), dtype=pd,
+                                    init="zeros")
+        s["conv_C_bias"] = ParamDef((n,), ("ssm_state",), dtype=pd,
+                                    init="zeros")
+    return s
 
 
-def _causal_conv(x, w, state=None):
-    """Depthwise causal conv. x: (B,S,C); w: (W,C); state: (B,W-1,C) or None."""
+def _causal_conv(x, w, state=None, bias=None):
+    """Depthwise causal conv. x: (B,S,C); w: (W,C); state: (B,W-1,C) or
+    None; bias: (C,) or None."""
     W = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], W - 1, x.shape[2]), x.dtype)
@@ -50,8 +59,15 @@ def _causal_conv(x, w, state=None):
         pad = state.astype(x.dtype)
     xp = jnp.concatenate([pad, x], axis=1)               # (B, S+W-1, C)
     out = sum(xp[:, i:i + x.shape[1], :] * w[i] for i in range(W))
+    if bias is not None:
+        out = out + bias
     new_state = xp[:, -(W - 1):, :]
     return out, new_state
+
+
+def _conv_bias(params, name, dt_):
+    b = params.get(f"conv_{name}_bias")
+    return None if b is None else b.astype(dt_)
 
 
 @jax.named_scope("ssd_scan")
@@ -130,9 +146,12 @@ def ssm_apply(params, x, cfg: ArchConfig, cache=None):
     Cm = jnp.einsum("bsd,dn->bsn", x, params["w_C"].astype(dt_))
     dt = jnp.einsum("bsd,dh->bsh", x, params["w_dt"].astype(dt_))
 
-    xs, _ = _causal_conv(xs, params["conv_x"].astype(dt_))
-    Bm, _ = _causal_conv(Bm, params["conv_B"].astype(dt_))
-    Cm, _ = _causal_conv(Cm, params["conv_C"].astype(dt_))
+    xs, _ = _causal_conv(xs, params["conv_x"].astype(dt_),
+                         bias=_conv_bias(params, "x", dt_))
+    Bm, _ = _causal_conv(Bm, params["conv_B"].astype(dt_),
+                         bias=_conv_bias(params, "B", dt_))
+    Cm, _ = _causal_conv(Cm, params["conv_C"].astype(dt_),
+                         bias=_conv_bias(params, "C", dt_))
     xs, Bm, Cm = jax.nn.silu(xs), jax.nn.silu(Bm), jax.nn.silu(Cm)
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) +
@@ -157,46 +176,95 @@ def init_ssm_cache(cfg: ArchConfig, batch: int):
     H, P, N = cfg.ssm_num_heads, s.head_dim, s.state_size
     W = s.conv_width
     return {
-        "state": jnp.zeros((batch, H, P, N), jnp.float32),
+        "state": jnp.zeros((batch, H, P, N), s.state_dtype),
         "conv_x": jnp.zeros((batch, W - 1, cfg.ssm_d_inner), cfg.dtype),
         "conv_B": jnp.zeros((batch, W - 1, N), cfg.dtype),
         "conv_C": jnp.zeros((batch, W - 1, N), cfg.dtype),
     }
 
 
-def ssm_decode_step(params, x, cfg: ArchConfig, cache):
-    """x: (B, 1, D) -> (y (B,1,D), new cache)."""
+def _layer_of(t, layer, fresh=None):
+    if layer is not None:
+        t = jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
+    return t if fresh is None else jnp.where(fresh, jnp.zeros_like(t), t)
+
+
+def _put_layer(t, new, layer):
+    if layer is None:
+        return new
+    return jax.lax.dynamic_update_index_in_dim(t, new.astype(t.dtype),
+                                               layer, 0)
+
+
+def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index,
+                    layer=None):
+    """x: (B, 1, D) at position ``cache_index`` -> (y (B,1,D), new cache).
+
+    At position 0 the batch's sequences begin: no state or conv window is
+    carried in from the slot's last sequence (KV needs no such reset, its
+    positions are masked).
+
+    ``layer`` (a traced int32 scalar), when given, says the cache leaves
+    are the stack of every Mamba layer's, (L, B, ...), that a layer scan
+    carries: the layer's conv windows and state are read from it at
+    ``layer`` and its new ones written back there in place, so a donated
+    stack is updated where it lies. Without it the leaves are one layer's.
+
+    Named scopes: ``ssm_in`` the input projections, ``ssm_conv`` the
+    convolution and its window, ``ssm_state`` the state's decay, update,
+    in-place write and read-out, ``ssm_out`` the output projection.
+    """
     s = cfg.ssm
     dt_ = jnp.dtype(cfg.dtype)
     H, P, N = cfg.ssm_num_heads, s.head_dim, s.state_size
     B_ = x.shape[0]
+    fresh = cache_index == 0
 
-    z = jnp.einsum("bsd,de->bse", x, params["w_z"].astype(dt_))
-    xs = jnp.einsum("bsd,de->bse", x, params["w_x"].astype(dt_))
-    Bm = jnp.einsum("bsd,dn->bsn", x, params["w_B"].astype(dt_))
-    Cm = jnp.einsum("bsd,dn->bsn", x, params["w_C"].astype(dt_))
-    dt = jnp.einsum("bsd,dh->bsh", x, params["w_dt"].astype(dt_))
+    with jax.named_scope("ssm_in"):
+        z = jnp.einsum("bsd,de->bse", x, params["w_z"].astype(dt_))
+        xs = jnp.einsum("bsd,de->bse", x, params["w_x"].astype(dt_))
+        Bm = jnp.einsum("bsd,dn->bsn", x, params["w_B"].astype(dt_))
+        Cm = jnp.einsum("bsd,dn->bsn", x, params["w_C"].astype(dt_))
+        dt = jnp.einsum("bsd,dh->bsh", x, params["w_dt"].astype(dt_))
 
-    xs, conv_x = _causal_conv(xs, params["conv_x"].astype(dt_), cache["conv_x"])
-    Bm, conv_B = _causal_conv(Bm, params["conv_B"].astype(dt_), cache["conv_B"])
-    Cm, conv_C = _causal_conv(Cm, params["conv_C"].astype(dt_), cache["conv_C"])
-    xs, Bm, Cm = jax.nn.silu(xs), jax.nn.silu(Bm), jax.nn.silu(Cm)
+    new_cache = {}
+    with jax.named_scope("ssm_conv"):
+        out = []
+        for name, t in (("x", xs), ("B", Bm), ("C", Cm)):
+            key = f"conv_{name}"
+            t, window = _causal_conv(
+                t, params[key].astype(dt_),
+                _layer_of(cache[key], layer, fresh),
+                bias=_conv_bias(params, name, dt_))
+            new_cache[key] = _put_layer(cache[key], window, layer)
+            out.append(jax.nn.silu(t))
+        xs, Bm, Cm = out
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) +
-                         params["dt_bias"].astype(jnp.float32))[:, 0]   # (B,H)
-    A = -jnp.exp(params["A_log"].astype(jnp.float32))
-    a = jnp.exp(dt * A[None, :])                                        # (B,H)
+    with jax.named_scope("ssm_state"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                             params["dt_bias"].astype(jnp.float32))[:, 0]
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        a = jnp.exp(dt * A[None, :])                                # (B,H)
 
-    xh = xs.reshape(B_, H, P).astype(jnp.float32)
-    xdt = xh * dt[..., None]
-    state = cache["state"] * a[:, :, None, None] + jnp.einsum(
-        "bhp,bn->bhpn", xdt, Bm[:, 0].astype(jnp.float32))
-    y = jnp.einsum("bhpn,bn->bhp", state, Cm[:, 0].astype(jnp.float32))
-    y = y + xh * params["D_skip"].astype(jnp.float32)[None, :, None]
-    y = y.reshape(B_, 1, H * P).astype(dt_)
-    y = y * jax.nn.silu(z)
+        xh = xs.reshape(B_, H, P).astype(jnp.float32)
+        xdt = xh * dt[..., None]
+        # the update and read-out are elementwise products and a sum, not
+        # dot_generals: exact in float32 on every backend, and an update
+        # XLA fuses into the in-place write, reading the old state where
+        # it lies (an outer-product einsum makes it copy the state out)
+        Bf = Bm[:, 0].astype(jnp.float32)[:, None, None, :]
+        Cf = Cm[:, 0].astype(jnp.float32)[:, None, None, :]
+        state = _layer_of(cache["state"], layer, fresh) \
+            * a[:, :, None, None] + xdt[..., None] * Bf
+        new_cache["state"] = _put_layer(cache["state"], state, layer)
+        # the read-out reads the state where it was written: no second
+        # live copy of a layer's state
+        state = _layer_of(new_cache["state"], layer)
+        y = jnp.sum(state * Cf, axis=-1)
+        y = y + xh * params["D_skip"].astype(jnp.float32)[None, :, None]
+        y = y.reshape(B_, 1, H * P).astype(dt_)
+        y = y * jax.nn.silu(z)
     y = rmsnorm({"scale": params["norm"]}, y, cfg.norm_eps)
-    out = jnp.einsum("bse,ed->bsd", y, params["w_out"].astype(dt_))
-    new_cache = {"state": state, "conv_x": conv_x, "conv_B": conv_B,
-                 "conv_C": conv_C}
+    with jax.named_scope("ssm_out"):
+        out = jnp.einsum("bse,ed->bsd", y, params["w_out"].astype(dt_))
     return out, new_cache

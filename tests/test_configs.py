@@ -8,7 +8,7 @@ from repro.configs.base import phys_vocab
 EXPECTED_ARCHS = {
     "zamba2-2.7b", "internlm2-20b", "granite-3-2b", "phi4-mini-3.8b",
     "qwen2.5-32b", "pixtral-12b", "seamless-m4t-medium", "mixtral-8x7b",
-    "qwen3-moe-235b-a22b", "mamba2-370m",
+    "qwen3-moe-235b-a22b", "mamba2-370m", "granite-4.0-h-micro",
 }
 
 
@@ -36,14 +36,15 @@ def test_exact_dims():
 
 def test_cell_matrix():
     cells = live_cells()
-    assert len(cells) == 40
+    assert len(cells) == 44
     live = [c for c in cells if c[2]]
     skipped = [c for c in cells if not c[2]]
-    assert len(live) == 33 and len(skipped) == 7
+    assert len(live) == 37 and len(skipped) == 7
     # long_500k runs only for sub-quadratic archs
     for arch, shape, ok, why in cells:
         if shape == "long_500k":
-            expect = arch in ("zamba2-2.7b", "mixtral-8x7b", "mamba2-370m")
+            expect = arch in ("zamba2-2.7b", "mixtral-8x7b", "mamba2-370m",
+                              "granite-4.0-h-micro")
             assert ok == expect, (arch, ok, why)
 
 

@@ -65,9 +65,9 @@ def test_repack_compiles(one_chip):
         _spec(one_chip, (64,), jnp.int32)))
 
 
-def _serve_step(cfg, sharding, rows, cache_len, **jit_kw):
-    """The chip's compiled row-stable decode step over ``rows`` sequences
-    and a ``cache_len``-deep cache."""
+def _serve_step(cfg, sharding, rows, cache_len, row_stable=True, **jit_kw):
+    """The chip's compiled decode step (row-stable by default) over ``rows``
+    sequences and a ``cache_len``-deep cache."""
     from repro.models import model as M
     from repro.models.train import make_serve_step
 
@@ -78,7 +78,8 @@ def _serve_step(cfg, sharding, rows, cache_len, **jit_kw):
                           M.abstract_params(cfg))
     cache = jax.tree.map(spec, jax.eval_shape(
         lambda: M.init_cache(cfg, rows, cache_len)))
-    return jax.jit(make_serve_step(cfg, row_stable=True), **jit_kw).lower(
+    return jax.jit(make_serve_step(cfg, row_stable=row_stable),
+                   **jit_kw).lower(
         params, cache, _spec(sharding, (rows, 1), jnp.int32),
         _spec(sharding, (), jnp.int32)).compile()
 
@@ -128,3 +129,38 @@ def test_decode_step_writes_the_cache_in_place(one_chip):
             update = shapes[operands.split(", ")[1]]
             assert shape == "bf16[40,256,256,8,64]"
             assert update == "bf16[1,256,1,8,64]", update
+
+
+def test_hybrid_decode_step_carries_its_state_in_place(one_chip):
+    """granite-4.0-h-micro's step at batch 96 and cache 256, the cache
+    donated: the (36, 96, 64, 64, 128) float32 SSM state, the conv windows
+    and the (4, 96, 256, 8, 64) K and V are carried through the layer scan
+    and each layer's slice is updated where it lies. No state-sized copy or
+    dynamic-slice is left (the update reads the old state inside the
+    in-place write), the temporaries are under 0.1 GiB, the output aliases
+    the donated cache, and the whole step fits one chip's 15.75 GiB."""
+    from repro.configs import get_config
+    cfg = get_config("granite-4.0-h-micro")
+    compiled = _serve_step(cfg, one_chip, 96, 256, row_stable=False,
+                           donate_argnums=(1,))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1 * 2**30
+    state_bytes = 36 * 96 * 64 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2**30
+
+    # buffers: instructions outside the fusions' own computations (inside
+    # one, a dynamic-slice is a read fused into its consumer)
+    text = compiled.as_text()
+    fused = set(re.findall(r"kind=k\w+, calls=(%[\w.-]+)", text))
+    bodies = re.split(r"\n(?=\S)", text)
+    buffers = "\n".join(b for b in bodies
+                        if b.split(" ", 1)[0] not in fused)
+    big = re.findall(r"= ((?:f32\[(?:36,|1,)?96,64,64,128\]|"
+                     r"bf16\[(?:4,|1,)?96,256,8,64\]))\S* ([\w-]+)\(",
+                     buffers)
+    assert big
+    for shape, op in big:
+        assert op not in ("copy", "dynamic-slice"), (shape, op)
+        assert shape.startswith(("f32[36,", "bf16[4,")), (shape, op)
