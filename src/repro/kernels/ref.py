@@ -55,6 +55,25 @@ def ssd_reference(xdt, a, bm, cm):
     return jnp.moveaxis(ys, 0, 2).astype(xdt.dtype)    # (B,H,S,P)
 
 
+def ssm_state_update_reference(state, layer, fresh, a, xdt, bm, cm):
+    """One token's Mamba-2 state update and read-out as XLA ops: the update
+    written into the stack at ``layer``, then read back for the read-out.
+
+    state: (L, B, H, P, N); a: (B, H); xdt: (B, H, P); bm, cm: (B, N).
+    h' = h * a + xdt (outer) B, h taken as zero where ``fresh``;
+    y = sum_n h' * C. Returns (the stack, y (B, H, P) float32)."""
+    f32 = jnp.float32
+    h = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    h = jnp.where(fresh, jnp.zeros_like(h), h)
+    new = h * a.astype(f32)[:, :, None, None] \
+        + xdt.astype(f32)[..., None] * bm.astype(f32)[:, None, None, :]
+    state = jax.lax.dynamic_update_index_in_dim(state, new.astype(state.dtype),
+                                                layer, 0)
+    new = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    y = jnp.sum(new.astype(f32) * cm.astype(f32)[:, None, None, :], axis=-1)
+    return state, y
+
+
 def repack_reference(src, idx):
     """out[i] = src[idx[i]]."""
     return src[idx]

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops
 from repro.models.params import ParamDef
 from repro.models.layers import rmsnorm
 
@@ -208,11 +209,16 @@ def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index,
     are the stack of every Mamba layer's, (L, B, ...), that a layer scan
     carries: the layer's conv windows and state are read from it at
     ``layer`` and its new ones written back there in place, so a donated
-    stack is updated where it lies. Without it the leaves are one layer's.
+    stack is updated where it lies. Without it the leaves are one layer's,
+    and the state is taken as a stack of one.
+
+    The state's decay, update and read-out are one pass over the layer's
+    state (``repro.kernels.ssm_state``): it is read once, written in place
+    once and read out while it is in fast memory, in float32.
 
     Named scopes: ``ssm_in`` the input projections, ``ssm_conv`` the
-    convolution and its window, ``ssm_state`` the state's decay, update,
-    in-place write and read-out, ``ssm_out`` the output projection.
+    convolution and its window, ``ssm_state`` the state's one pass,
+    ``ssm_out`` the output projection.
     """
     s = cfg.ssm
     dt_ = jnp.dtype(cfg.dtype)
@@ -247,20 +253,14 @@ def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index,
         a = jnp.exp(dt * A[None, :])                                # (B,H)
 
         xh = xs.reshape(B_, H, P).astype(jnp.float32)
-        xdt = xh * dt[..., None]
-        # the update and read-out are elementwise products and a sum, not
-        # dot_generals: exact in float32 on every backend, and an update
-        # XLA fuses into the in-place write, reading the old state where
-        # it lies (an outer-product einsum makes it copy the state out)
-        Bf = Bm[:, 0].astype(jnp.float32)[:, None, None, :]
-        Cf = Cm[:, 0].astype(jnp.float32)[:, None, None, :]
-        state = _layer_of(cache["state"], layer, fresh) \
-            * a[:, :, None, None] + xdt[..., None] * Bf
-        new_cache["state"] = _put_layer(cache["state"], state, layer)
-        # the read-out reads the state where it was written: no second
-        # live copy of a layer's state
-        state = _layer_of(new_cache["state"], layer)
-        y = jnp.sum(state * Cf, axis=-1)
+        # one pass over the layer's state: read once, written in place
+        # once, read out against C while it is in fast memory
+        stack = cache["state"] if layer is not None else cache["state"][None]
+        stack, y = ops.ssm_state_update(
+            stack, 0 if layer is None else layer, fresh, a,
+            xh * dt[..., None], Bm[:, 0], Cm[:, 0],
+            interpret=ops.interpret_here())
+        new_cache["state"] = stack if layer is not None else stack[0]
         y = y + xh * params["D_skip"].astype(jnp.float32)[None, :, None]
         y = y.reshape(B_, 1, H * P).astype(dt_)
         y = y * jax.nn.silu(z)

@@ -134,3 +134,48 @@ def test_repack(nblocks, block, width, nout):
     out = ops.repack(src, idx, interpret=True)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(ref.repack_reference(src, idx)))
+
+
+SSM_STATE_CASES = [
+    # (L, B, H, P, N): a tiny stack, and the published Mamba-2 head shape
+    # (granite-4.0-h-micro, mamba2) at a small batch
+    (3, 2, 4, 8, 16),
+    (3, 2, 64, 64, 128),
+]
+
+
+@pytest.mark.parametrize("fresh", [False, True], ids=["carried", "fresh"])
+@pytest.mark.parametrize("L,B,H,P,N", SSM_STATE_CASES)
+def test_ssm_state_update(L, B, H, P, N, fresh):
+    """The one-pass update and read-out of the middle layer of a stack
+    equals the XLA expression within float32 rounding (the sum over N may
+    run in another order), and leaves every other layer bit for bit."""
+    state = _rand((L, B, H, P, N), jnp.float32)
+    a = jnp.exp(-jnp.abs(_rand((B, H), jnp.float32)))
+    xdt = _rand((B, H, P), jnp.float32)
+    bm, cm = _rand((B, N), jnp.float32), _rand((B, N), jnp.float32)
+    layer = L // 2
+    got, y = ops.ssm_state_update(state, jnp.int32(layer), jnp.bool_(fresh),
+                                  a, xdt, bm, cm, interpret=True)
+    want, y_want = ref.ssm_state_update_reference(
+        state, layer, fresh, a, xdt, bm, cm)
+    assert got.dtype == y.dtype == jnp.float32
+    for g, w in ((got[layer], want[layer]), (y, y_want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert np.max(np.abs(g - w)) <= 1e-6 * np.max(np.abs(w))
+    others = [i for i in range(L) if i != layer]
+    np.testing.assert_array_equal(np.asarray(got)[others],
+                                  np.asarray(state)[others])
+
+
+@pytest.mark.parametrize("heads,p,n,want", [
+    (64, 64, 128, 64),        # a sequence's 2 MiB of state a block
+    (32, 64, 128, 32),
+    (128, 64, 256, 32),
+    (4, 8, 16, 4),            # all the heads of a small model
+    (48, 64, 256, 24),        # a multiple of 8, not all of them
+    (24, 256, 512, 8),        # none fits: the least the tiling allows
+])
+def test_ssm_state_block_heads(heads, p, n, want):
+    from repro.kernels.ssm_state import block_heads
+    assert block_heads(heads, p, n) == want

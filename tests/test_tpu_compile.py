@@ -59,6 +59,18 @@ def test_ssd_scan_compiles(one_chip):
         _spec(one_chip, (B, S, N)), _spec(one_chip, (B, S, N)), chunk=256))
 
 
+def test_ssm_state_update_compiles(one_chip):
+    """granite-4.0-h-micro's stacked state at batch 96: 36 layers of
+    (96, 64, 64, 128) float32, updated at a traced layer."""
+    L, B, H, P, N = 36, 96, 64, 64, 128
+    f32 = jnp.float32
+    _assert_kernel(ops.ssm_state_update.lower(
+        _spec(one_chip, (L, B, H, P, N), f32), _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (), jnp.bool_), _spec(one_chip, (B, H), f32),
+        _spec(one_chip, (B, H, P), f32), _spec(one_chip, (B, N), f32),
+        _spec(one_chip, (B, N), f32)))
+
+
 def test_repack_compiles(one_chip):
     _assert_kernel(ops.repack.lower(
         _spec(one_chip, (64, 8, 2048), jnp.float32),
@@ -131,15 +143,20 @@ def test_decode_step_writes_the_cache_in_place(one_chip):
             assert update == "bf16[1,256,1,8,64]", update
 
 
-def test_hybrid_decode_step_carries_its_state_in_place(one_chip):
+def test_hybrid_decode_step_carries_its_state_in_place(one_chip,
+                                                        monkeypatch):
     """granite-4.0-h-micro's step at batch 96 and cache 256, the cache
     donated: the (36, 96, 64, 64, 128) float32 SSM state, the conv windows
     and the (4, 96, 256, 8, 64) K and V are carried through the layer scan
     and each layer's slice is updated where it lies. No state-sized copy or
-    dynamic-slice is left (the update reads the old state inside the
-    in-place write), the temporaries are under 0.1 GiB, the output aliases
-    the donated cache, and the whole step fits one chip's 15.75 GiB."""
+    dynamic-slice is left, each Mamba layer's state is read by one
+    operation (the kernel that updates it in place and reads it out), the
+    temporaries are under 0.1 GiB, the output aliases the donated cache,
+    and the whole step fits one chip's 15.75 GiB."""
     from repro.configs import get_config
+    # the model asks the backend, here the CPU, whether to interpret its
+    # kernel: the described chip compiles it
+    monkeypatch.setattr(ops, "interpret_here", lambda: False)
     cfg = get_config("granite-4.0-h-micro")
     compiled = _serve_step(cfg, one_chip, 96, 256, row_stable=False,
                            donate_argnums=(1,))
@@ -164,3 +181,19 @@ def test_hybrid_decode_step_carries_its_state_in_place(one_chip):
     for shape, op in big:
         assert op not in ("copy", "dynamic-slice"), (shape, op)
         assert shape.startswith(("f32[36,", "bf16[4,")), (shape, op)
+
+    # the operations that take a state stack as an operand, computation by
+    # computation: one in each Mamba run's scan body, the kernel
+    shapes = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))
+    state = re.compile(r"f32\[(?:36,|1,)?96,64,64,128\]")
+    moves = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+    readers = []
+    for body in bodies:
+        ops_here = [op for op, operands in re.findall(
+            r"\n\s*(?:ROOT )?%[\w.-]+ = .*? ([\w-]+)\(([^)]*)\)", body)
+            if op not in moves and any(
+                state.fullmatch(shapes.get(o, ""))
+                for o in re.findall(r"%[\w.-]+", operands))]
+        if ops_here:
+            readers.append(ops_here)
+    assert readers == [["custom-call"]] * 2, readers
