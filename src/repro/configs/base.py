@@ -130,6 +130,11 @@ class ArchConfig:
         elif self.residual_multiplier != 1.0:
             raise ValueError(f"{self.name}: only the interleaved hybrid's "
                              f"layers run a residual_multiplier")
+        if self.shared_attention_every \
+                and self.num_layers % self.shared_attention_every:
+            raise ValueError(f"{self.name}: {self.num_layers} layers are no "
+                             f"whole number of groups of "
+                             f"{self.shared_attention_every}")
 
     # convenience ------------------------------------------------------
     @property

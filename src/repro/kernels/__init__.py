@@ -5,7 +5,8 @@ wrappers of ``ops.py``. ``ref.py`` holds the oracle of every one, the ground
 truth of tests/test_kernels.py (interpret mode); tests/test_tpu_compile.py
 compiles each for a described TPU v5e at model widths.
 
-On the model's path: ``ssm_state``, the Mamba-2 decode state's one pass
+On the model's path: ``ssm_state``, the Mamba-2 decode state's one pass,
+in place in the stack of every Mamba layer's state at a traced layer
 (``models/ssm.py`` ``ssm_decode_step``). Off it, called only by tests:
 ``flash_attention``, ``ssd_scan`` and ``blockcyclic``.
 """

@@ -292,38 +292,34 @@ def _attend_cache(q, k, v, valid, cfg: ArchConfig):
 
 
 def _seq_rows(t, layer, b):
-    """Sequence ``b``'s rows (1, S, Hkv, hd) of a per-layer cache leaf, or
-    of layer ``layer`` of a stacked one, sliced where they lie."""
-    if layer is None:
-        return jax.lax.dynamic_slice_in_dim(t, b, 1, 0)
+    """Sequence ``b``'s rows (1, S, Hkv, hd) of layer ``layer`` of a
+    stacked cache leaf, sliced where they lie."""
     return jax.lax.dynamic_slice(t, (layer, b) + (0,) * (t.ndim - 2),
                                  (1, 1) + t.shape[2:])[0]
 
 
 def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
-                      layer=None, cross: bool = False,
-                      row_stable: bool = False):
-    """One-token decode against a KV cache.
+                      layer, cross: bool = False, row_stable: bool = False):
+    """One-token decode of layer ``layer`` against the stacked KV cache.
 
-    cache: {"k","v"}: (B, S_cache, Hkv, hd).  ``cache_index`` is the absolute
-    position of the new token; for SWA the cache is a rolling buffer of
-    ``window`` slots.  ``x`` may carry padding rows after the cache's B
-    sequences (``decode_step(row_stable=True)``); they stay out of the cache.
+    cache: {"k","v"}: every layer's, (L, B, S_cache, Hkv, hd), as a layer
+    scan carries it; ``layer`` is a traced int32 scalar, ``0 <= layer <
+    L``. ``cache_index`` is the absolute position of the new token; for
+    SWA the cache is a rolling buffer of ``window`` slots.  ``x`` may
+    carry padding rows after the cache's B sequences
+    (``decode_step(row_stable=True)``); they stay out of the cache.
 
-    ``layer`` (a traced int32 scalar, ``0 <= layer < L``), when given, says
-    the leaves are the whole stack (L, B, S_cache, Hkv, hd) that a layer
-    scan carries: the new token's K and V rows are written into it in place
-    at (``layer``, :, slot), one ``dynamic_update_slice`` each, attention
+    The new token's K and V rows are written into the stack in place at
+    (``layer``, :, slot), one ``dynamic_update_slice`` each, attention
     reads layer ``layer``'s rows straight from the stack, and the stack
-    comes back with only those rows changed.  Without it (the cross cache,
-    the hybrid's shared cache) the leaves are one layer's.
+    comes back with only those rows changed. A ``cross`` cache is only
+    read.
 
     ``row_stable`` reads the cache one sequence at a time, so that a
     sequence's scores do not depend on how many others the program holds.
     """
     dt = jnp.dtype(cfg.dtype)
-    stacked = layer is not None
-    B, rows = cache["k"].shape[int(stacked)], x.shape[0]
+    B, rows = cache["k"].shape[1], x.shape[0]
     pos = jnp.full((rows, 1), cache_index)
     q, k_new, v_new = _project_qkv(params, x, cfg, pos, rope=not cross)
     if row_stable:
@@ -333,13 +329,11 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     q, k_new, v_new = q[:B], k_new[:B], v_new[:B]
     if cross:
         k, v = cache["k"], cache["v"]
-        valid = jnp.ones((k.shape[1],), bool)
+        valid = jnp.ones((k.shape[2],), bool)
     else:
-        S = cache["k"].shape[-3]
+        S = cache["k"].shape[2]
         slot = jnp.mod(cache_index, S) if cfg.attention == "swa" else cache_index
-        at = (0, slot, 0, 0)
-        if stacked:
-            k_new, v_new, at = k_new[None], v_new[None], (layer,) + at
+        k_new, v_new, at = k_new[None], v_new[None], (layer, 0, slot, 0, 0)
         with jax.named_scope("kv_write"):
             k = jax.lax.dynamic_update_slice(
                 cache["k"], k_new.astype(cache["k"].dtype), at)
@@ -367,9 +361,8 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
                     _seq_rows(v, layer, b), valid, cfg)[0]
             _, o = jax.lax.scan(one, jnp.int32(0), q)
         else:
-            if stacked:
-                k, v = (jax.lax.dynamic_index_in_dim(t, layer, 0, False)
-                        for t in (k, v))
+            k, v = (jax.lax.dynamic_index_in_dim(t, layer, 0, False)
+                    for t in (k, v))
             o = _attend_cache(q, k, v, valid, cfg)
     with jax.named_scope("attn_out"):
         o = jnp.pad(o, ((0, rows - B), (0, 0), (0, 0), (0, 0)))

@@ -51,11 +51,10 @@ def decoder_block_apply(params, x, cfg: ArchConfig, *, positions,
 
 
 def decoder_block_decode(params, x, cfg: ArchConfig, cache, *, cache_index,
-                         layer=None, cross_cache=None,
-                         row_stable: bool = False):
-    """One-token decode. cache: {"k","v"}, the stack of every layer's where
-    ``layer`` is given (``attention.decode_attn_apply``); cross_cache:
-    precomputed enc K/V of this layer."""
+                         layer, cross_cache=None, row_stable: bool = False):
+    """One-token decode of layer ``layer``. cache: {"k","v"}, the stack of
+    every layer's (``attention.decode_attn_apply``); cross_cache: the stack
+    of every layer's precomputed encoder K/V."""
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     a, cache = attn.decode_attn_apply(params["attn"], h, cfg, cache,
                                       cache_index=cache_index, layer=layer,
@@ -64,8 +63,8 @@ def decoder_block_decode(params, x, cfg: ArchConfig, cache, *, cache_index,
     if cross_cache is not None:
         h = rmsnorm(params["ln_x"], x, cfg.norm_eps)
         a, _ = attn.decode_attn_apply(params["cross"], h, cfg, cross_cache,
-                                      cache_index=cache_index, cross=True,
-                                      row_stable=row_stable)
+                                      cache_index=cache_index, layer=layer,
+                                      cross=True, row_stable=row_stable)
         x = x + a
     h = rmsnorm(params["ln2"], x, cfg.norm_eps)
     if cfg.is_moe:
@@ -91,16 +90,9 @@ def ssm_block_apply(params, x, cfg: ArchConfig):
     return constrain(y, "act_batch", "act_seq_blk", "act_embed")
 
 
-def ssm_block_decode(params, x, cfg: ArchConfig, cache, cache_index):
-    h = rmsnorm(params["ln"], x, cfg.norm_eps)
-    y, cache = ssm_mod.ssm_decode_step(params["ssm"], h, cfg, cache,
-                                       cache_index)
-    return x + y, cache
-
-
 # ----------------------------------------------------------------------
-# Interleaved hybrid layer (granite-4.0-h): x += r·mixer(norm(x));
-# x += r·mlp(norm(x)), the mixer Mamba-2 or attention
+# Mixer layer (granite-4.0-h): x += r·mixer(norm(x)); x += r·mlp(norm(x)),
+# the mixer Mamba-2 or attention; its decode serves every Mamba model
 # ----------------------------------------------------------------------
 
 def mixer_schema(cfg: ArchConfig, kind: str):
@@ -137,14 +129,15 @@ def interleaved_layer_apply(mixer, mlp_params, x, cfg: ArchConfig, kind: str,
                      "act_batch", "act_seq_blk", "act_embed")
 
 
-def interleaved_layer_decode(mixer, mlp_params, x, cfg: ArchConfig,
-                             kind: str, cache, *, layer, cache_index):
-    """One-token decode of one layer. ``cache`` is the stack of the
+def mixer_layer_decode(mixer, mlp_params, x, cfg: ArchConfig, kind: str,
+                       cache, *, layer, cache_index, row_stable: bool):
+    """One-token decode of one layer of a model with Mamba layers, its MLP
+    only where ``mlp_params`` is given. ``kind`` "mamba" runs a Mamba-2
+    mixer, any other kind attention, read one sequence at a time where
+    ``row_stable`` (``decode_attn_apply``). ``cache`` is the stack of the
     layers of ``kind`` (the SSM state and conv windows, or K and V) and
-    ``layer`` the index of this one in it; its rows are written in place.
-    Attention reads the stack one sequence at a time (``decode_attn_apply``'s
-    row-stable read): read as a batch, the layer's K and V are sliced out
-    and the whole stack laid out anew each step."""
+    ``layer`` the index of this one in it; its rows are written in
+    place."""
     h = rmsnorm(mixer["ln"], x, cfg.norm_eps)
     if kind == "mamba":
         y, cache = ssm_mod.ssm_decode_step(mixer["ssm"], h, cfg, cache,
@@ -152,8 +145,10 @@ def interleaved_layer_decode(mixer, mlp_params, x, cfg: ArchConfig,
     else:
         y, cache = attn.decode_attn_apply(mixer["attn"], h, cfg, cache,
                                           cache_index=cache_index,
-                                          layer=layer, row_stable=True)
+                                          layer=layer, row_stable=row_stable)
     x = _residual(x, y, cfg)
+    if mlp_params is None:
+        return x, cache
     h = rmsnorm(mlp_params["ln"], x, cfg.norm_eps)
     return _residual(x, mlp(mlp_params["mlp"], h, cfg), cfg), cache
 

@@ -7,6 +7,7 @@ compile time on the 512-device mesh — stays O(1) in depth.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -91,11 +92,24 @@ def _scan_layers(layer_params, x, body, cfg: ArchConfig):
     return x, jnp.sum(auxs)
 
 
-def _period_runs(cfg: ArchConfig):
-    """The mixer period as runs of one kind: ``(kind, first index among
-    the period's layers of that kind, first layer, length)``."""
-    runs, seen = [], {"mamba": 0, "attention": 0}
-    for i, kind in enumerate(cfg.mixer_period):
+def _layer_period(cfg: ArchConfig):
+    """A model with Mamba layers as a period of layer kinds and the number
+    of periods in its depth: Mamba-2 one "mamba" layer; zamba2
+    ``shared_attention_every`` of them and its shared attention block,
+    "shared"; the interleaved hybrid its ``mixer_period``."""
+    if cfg.is_interleaved:
+        return cfg.mixer_period, cfg.num_layers // len(cfg.mixer_period)
+    if cfg.is_hybrid:
+        every = cfg.shared_attention_every
+        return ("mamba",) * every + ("shared",), cfg.num_layers // every
+    return ("mamba",), cfg.num_layers
+
+
+def _period_runs(period):
+    """``period`` as runs of one kind: ``(kind, first index among the
+    period's layers of that kind, first layer, length)``."""
+    runs, seen = [], {kind: 0 for kind in period}
+    for i, kind in enumerate(period):
         if runs and runs[-1][0] == kind:
             k, first, layer, n = runs[-1]
             runs[-1] = (k, first, layer, n + 1)
@@ -105,26 +119,27 @@ def _period_runs(cfg: ArchConfig):
     return runs, seen
 
 
-def _interleaved_scan(cfg: ArchConfig, carry, layer_fn, remat: bool = False):
-    """The interleaved hybrid's layers, scanned period by period: an outer
-    scan over the periods and in each one a scan per run of layers of one
-    kind (granite-4.0-h: 5 Mamba, 1 attention, 4 Mamba).
-    ``layer_fn(carry, kind, index, layer)`` applies layer ``layer``, the
-    ``index``-th of its kind, both traced int32 scalars."""
-    runs, per_kind = _period_runs(cfg)
-    periods = cfg.num_layers // len(cfg.mixer_period)
+def _period_scan(period, periods: int, carry, layer_fn,
+                 remat: bool = False):
+    """Layers scanned period by period: an outer scan over the ``periods``
+    periods and in each one a scan per run of layers of one kind
+    (granite-4.0-h: 5 Mamba, 1 attention, 4 Mamba).
+    ``layer_fn(carry, kind, index, layer)`` applies the ``layer``-th layer
+    of the depth, the ``index``-th of its kind, both traced int32
+    scalars."""
+    runs, per_kind = _period_runs(period)
 
-    def period(c, p):
+    def one_period(c, p):
         for kind, first, layer0, n in runs:
             def body(cc, i, kind=kind, first=first, layer0=layer0):
                 return layer_fn(cc, kind, p * per_kind[kind] + first + i,
-                                p * len(cfg.mixer_period) + layer0 + i), None
+                                p * len(period) + layer0 + i), None
             if remat:
                 body = jax.checkpoint(body)
             c, _ = jax.lax.scan(body, c, jnp.arange(n))
         return c, None
 
-    carry, _ = jax.lax.scan(period, carry, jnp.arange(periods))
+    carry, _ = jax.lax.scan(one_period, carry, jnp.arange(periods))
     return carry
 
 
@@ -144,8 +159,8 @@ def _trunk(params, x, cfg: ArchConfig, positions, enc_out=None):
                 _layer_params(layers[kind], index),
                 _layer_params(layers["mlp"], l), h, cfg, kind,
                 positions=positions)
-        return _interleaved_scan(cfg, x, layer, remat=cfg.remat), \
-            jnp.float32(0.0)
+        return _period_scan(*_layer_period(cfg), x, layer,
+                            remat=cfg.remat), jnp.float32(0.0)
 
     if cfg.is_ssm:
         def body(h, lp):
@@ -237,43 +252,37 @@ def forward(params, cfg: ArchConfig, batch: Dict[str, jnp.ndarray]):
 # Decode (serving) path
 # ----------------------------------------------------------------------
 
+def _stacked(one, n: int):
+    """``n`` layers' caches: every leaf of the one-layer cache ``one``
+    broadcast along a new leading layer axis."""
+    return jax.tree.map(lambda t: jnp.broadcast_to(t, (n,) + t.shape), one)
+
+
 def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
                enc_len: Optional[int] = None):
-    """Decode-state pytree for one new token against a seq_len-deep context."""
+    """Decode-state pytree for one new token against a seq_len-deep
+    context: one stack per kind of layer state, each layer's at its
+    index."""
     L = cfg.num_layers
-    if cfg.is_ssm:
-        one = ssm_mod.init_ssm_cache(cfg, batch)
-        return {"layers": jax.tree.map(
-            lambda t: jnp.broadcast_to(t, (L,) + t.shape), one)}
-    if cfg.is_hybrid:
-        groups = cfg.num_layers // cfg.shared_attention_every
-        ssm_one = ssm_mod.init_ssm_cache(cfg, batch)
-        kv_one = attn_mod.init_kv_cache(cfg, batch, seq_len)
-        return {
-            "layers": jax.tree.map(
-                lambda t: jnp.broadcast_to(t, (L,) + t.shape), ssm_one),
-            "shared_kv": jax.tree.map(
-                lambda t: jnp.broadcast_to(t, (groups,) + t.shape), kv_one),
-        }
+    if cfg.ssm is None:
+        cache = {"layers": _stacked(
+            attn_mod.init_kv_cache(cfg, batch, seq_len), L)}
+        if cfg.is_encdec:
+            cache["cross"] = _stacked(attn_mod.init_kv_cache(
+                cfg, batch, enc_len or seq_len), L)
+        return cache
+    ssm_one = ssm_mod.init_ssm_cache(cfg, batch)
     if cfg.is_interleaved:
-        # every Mamba layer's state and conv windows, and every attention
-        # layer's K and V, stacked by kind: the decode scan carries both
         kinds = cfg.layer_types
-        ssm_one = ssm_mod.init_ssm_cache(cfg, batch)
-        kv_one = attn_mod.init_kv_cache(cfg, batch, seq_len)
-        return {"mamba": jax.tree.map(
-                    lambda t: jnp.broadcast_to(
-                        t, (kinds.count("mamba"),) + t.shape), ssm_one),
-                "attention": jax.tree.map(
-                    lambda t: jnp.broadcast_to(
-                        t, (kinds.count("attention"),) + t.shape), kv_one)}
-    kv_one = attn_mod.init_kv_cache(cfg, batch, seq_len)
-    cache = {"layers": jax.tree.map(
-        lambda t: jnp.broadcast_to(t, (L,) + t.shape), kv_one)}
-    if cfg.is_encdec:
-        cross_one = attn_mod.init_kv_cache(cfg, batch, enc_len or seq_len)
-        cache["cross"] = jax.tree.map(
-            lambda t: jnp.broadcast_to(t, (L,) + t.shape), cross_one)
+        return {"mamba": _stacked(ssm_one, kinds.count("mamba")),
+                "attention": _stacked(
+                    attn_mod.init_kv_cache(cfg, batch, seq_len),
+                    kinds.count("attention"))}
+    cache = {"layers": _stacked(ssm_one, L)}
+    if cfg.is_hybrid:
+        cache["shared_kv"] = _stacked(
+            attn_mod.init_kv_cache(cfg, batch, seq_len),
+            L // cfg.shared_attention_every)
     return cache
 
 
@@ -305,14 +314,12 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
                 row_stable: bool = False):
     """One-token decode. tokens: (B, 1) int32. Returns (logits, new cache).
 
-    Attention decoders carry the stacked KV cache (L, B, S, Hkv, hd) through
-    the layer scan: layer ``l`` writes the new token's K and V rows into it
-    in place at ``l`` and reads its attention rows from it there
-    (``attention.decode_attn_apply`` with ``layer``), so a donated cache is
-    updated where it lies, with no layer slice or whole-cache copy. The
-    interleaved hybrid's scan carries its Mamba layers' stacked state and
-    conv windows and its attention layers' stacked KV the same way, each
-    layer updating its own slice (``blocks.interleaved_layer_decode``).
+    The layer scan carries the cache, one stack per kind of layer state
+    (``init_cache``), and each layer reads and writes its own slice of its
+    stack at a traced index, in place: a donated cache is updated where it
+    lies, with no layer slice or whole-cache copy. Attention decoders scan
+    their layers in one scan; models with Mamba layers scan them period by
+    period (``_layer_period``).
 
     ``row_stable`` computes each sequence the same way whatever ``B`` is:
     the dense layers run on the batch padded to a multiple of ``ROW_TILE``
@@ -329,86 +336,52 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, cache_index, *,
         tokens = jnp.pad(tokens, ((0, -B % ROW_TILE), (0, 0)))
     x = embed(params["embed"], tokens, cfg)
 
-    if cfg.is_ssm:
-        def body(h, scanned):
-            lp, c = scanned
-            h, c = blocks.ssm_block_decode(lp, h, cfg, c, cache_index)
-            return h, c
-        with jax.named_scope("layers"):
-            x, new_cache = jax.lax.scan(body, x,
-                                        (params["layers"], cache["layers"]))
-        cache = {"layers": new_cache}
-
-    elif cfg.is_interleaved:
-        # the stacked SSM state, conv windows and KV are the scan's carry;
-        # each layer writes its own slice in place
-        layers = params["layers"]
-
-        def layer(carry, kind, index, l):
-            h, c = carry
-            h, own = blocks.interleaved_layer_decode(
-                _layer_params(layers[kind], index),
-                _layer_params(layers["mlp"], l), h, cfg, kind, c[kind],
-                layer=index, cache_index=cache_index)
-            return h, {**c, kind: own}
-
-        with jax.named_scope("layers"):
-            x, cache = _interleaved_scan(cfg, (x, cache), layer)
-
-    elif cfg.is_hybrid:
-        every = cfg.shared_attention_every
-        groups = cfg.num_layers // every
-        grouped = jax.tree.map(
-            lambda t: t.reshape(groups, every, *t.shape[1:]), params["layers"])
-        grouped_cache = jax.tree.map(
-            lambda t: t.reshape(groups, every, *t.shape[1:]), cache["layers"])
-        shared = params["shared_attn"]
-
-        def group_body(h, scanned):
-            glp, gc, kv = scanned
-
-            def inner(hh, sc):
-                lp, c = sc
-                hh, c = blocks.ssm_block_decode(lp, hh, cfg, c,
-                                                cache_index)
-                return hh, c
-            h, gc = jax.lax.scan(inner, h, (glp, gc))
-            hn = rmsnorm(shared["ln1"], h, cfg.norm_eps)
-            a, kv = attn_mod.decode_attn_apply(shared["attn"], hn, cfg, kv,
-                                               cache_index=cache_index)
-            h = h + a
-            hn = rmsnorm(shared["ln2"], h, cfg.norm_eps)
-            h = h + blocks.mlp(shared["mlp"], hn, cfg)
-            return h, (gc, kv)
-
-        with jax.named_scope("layers"):
-            x, (new_gc, new_kv) = jax.lax.scan(
-                group_body, x, (grouped, grouped_cache, cache["shared_kv"]))
-        cache = {
-            "layers": jax.tree.map(
-                lambda t: t.reshape(cfg.num_layers, *t.shape[2:]), new_gc),
-            "shared_kv": new_kv,
-        }
-
-    else:
-        # the stacked self-attention cache is the scan's carry, written one
-        # row per layer in place; the read-only cross cache is scanned
-        xs = (params["layers"], jnp.arange(cfg.num_layers))
-        if cfg.is_encdec:
-            xs += (cache["cross"],)
-
+    if cfg.ssm is None:
         def body(carry, sc):
             h, kv = carry
-            lp, layer, *cross = sc
+            lp, layer = sc
             h, kv = blocks.decoder_block_decode(
                 lp, h, cfg, kv, cache_index=cache_index, layer=layer,
-                cross_cache=cross[0] if cross else None,
-                row_stable=row_stable)
+                cross_cache=cache.get("cross"), row_stable=row_stable)
             return (h, kv), None
 
         with jax.named_scope("layers"):
-            (x, kv), _ = jax.lax.scan(body, (x, cache["layers"]), xs)
+            (x, kv), _ = jax.lax.scan(
+                body, (x, cache["layers"]),
+                (params["layers"], jnp.arange(cfg.num_layers)))
         cache = {**cache, "layers": kv}
+
+    else:
+        layers, shared = params["layers"], params.get("shared_attn")
+        # each kind of layer: its mixer's parameters at its index, its
+        # MLP's at its layer (None: no MLP), its cache stack's key, and
+        # whether its attention reads the cache one sequence at a time;
+        # zamba2's shared block keeps the batch read
+        if cfg.is_interleaved:
+            mlp = functools.partial(_layer_params, layers["mlp"])
+            kinds = {kind: (functools.partial(_layer_params, layers[kind]),
+                            mlp, kind, kind == "attention")
+                     for kind in ("mamba", "attention")}
+        else:
+            kinds = {"mamba": (functools.partial(_layer_params, layers),
+                               None, "layers", False),
+                     "shared": (lambda _: {"ln": shared["ln1"],
+                                           "attn": shared["attn"]},
+                                lambda _: {"ln": shared["ln2"],
+                                           "mlp": shared["mlp"]},
+                                "shared_kv", False)}
+
+        def layer(carry, kind, index, l):
+            h, c = carry
+            mixer, mlp, key, per_row = kinds[kind]
+            h, new = blocks.mixer_layer_decode(
+                mixer(index), None if mlp is None else mlp(l), h, cfg, kind,
+                c[key], layer=index, cache_index=cache_index,
+                row_stable=per_row)
+            return h, {**c, key: new}
+
+        with jax.named_scope("layers"):
+            x, cache = _period_scan(*_layer_period(cfg), (x, cache), layer)
 
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg)

@@ -184,33 +184,24 @@ def init_ssm_cache(cfg: ArchConfig, batch: int):
     }
 
 
-def _layer_of(t, layer, fresh=None):
-    if layer is not None:
-        t = jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
-    return t if fresh is None else jnp.where(fresh, jnp.zeros_like(t), t)
+def _window(stack, layer, fresh):
+    """Layer ``layer``'s conv window, zeros where the sequences begin."""
+    t = jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    return jnp.where(fresh, jnp.zeros_like(t), t)
 
 
-def _put_layer(t, new, layer):
-    if layer is None:
-        return new
-    return jax.lax.dynamic_update_index_in_dim(t, new.astype(t.dtype),
-                                               layer, 0)
-
-
-def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index,
-                    layer=None):
+def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index, layer):
     """x: (B, 1, D) at position ``cache_index`` -> (y (B,1,D), new cache).
+
+    The cache leaves are the stack of every Mamba layer's, (L, B, ...),
+    that a layer scan carries, and ``layer`` (a traced int32 scalar) is
+    this one's index in it: the layer's conv windows and state are read
+    from it at ``layer`` and its new ones written back there in place, so
+    a donated stack is updated where it lies.
 
     At position 0 the batch's sequences begin: no state or conv window is
     carried in from the slot's last sequence (KV needs no such reset, its
     positions are masked).
-
-    ``layer`` (a traced int32 scalar), when given, says the cache leaves
-    are the stack of every Mamba layer's, (L, B, ...), that a layer scan
-    carries: the layer's conv windows and state are read from it at
-    ``layer`` and its new ones written back there in place, so a donated
-    stack is updated where it lies. Without it the leaves are one layer's,
-    and the state is taken as a stack of one.
 
     The state's decay, update and read-out are one pass over the layer's
     state (``repro.kernels.ssm_state``): it is read once, written in place
@@ -239,10 +230,10 @@ def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index,
         for name, t in (("x", xs), ("B", Bm), ("C", Cm)):
             key = f"conv_{name}"
             t, window = _causal_conv(
-                t, params[key].astype(dt_),
-                _layer_of(cache[key], layer, fresh),
+                t, params[key].astype(dt_), _window(cache[key], layer, fresh),
                 bias=_conv_bias(params, name, dt_))
-            new_cache[key] = _put_layer(cache[key], window, layer)
+            new_cache[key] = jax.lax.dynamic_update_index_in_dim(
+                cache[key], window.astype(cache[key].dtype), layer, 0)
             out.append(jax.nn.silu(t))
         xs, Bm, Cm = out
 
@@ -255,12 +246,9 @@ def ssm_decode_step(params, x, cfg: ArchConfig, cache, cache_index,
         xh = xs.reshape(B_, H, P).astype(jnp.float32)
         # one pass over the layer's state: read once, written in place
         # once, read out against C while it is in fast memory
-        stack = cache["state"] if layer is not None else cache["state"][None]
-        stack, y = ops.ssm_state_update(
-            stack, 0 if layer is None else layer, fresh, a,
-            xh * dt[..., None], Bm[:, 0], Cm[:, 0],
-            interpret=ops.interpret_here())
-        new_cache["state"] = stack if layer is not None else stack[0]
+        new_cache["state"], y = ops.ssm_state_update(
+            cache["state"], layer, fresh, a, xh * dt[..., None], Bm[:, 0],
+            Cm[:, 0], interpret=ops.interpret_here())
         y = y + xh * params["D_skip"].astype(jnp.float32)[None, :, None]
         y = y.reshape(B_, 1, H * P).astype(dt_)
         y = y * jax.nn.silu(z)

@@ -6,10 +6,10 @@ seeded random weights from the benchmark's generator.
 * ``forward`` (the chunked SSD) gives the plain reference's logits
   (``chipbench/refs/granite_hybrid.py``);
 * prefill by decode through ``decode_step``, then greedy decoding, gives
-  the reference's full-forward logits at every position;
-* the decode scan that carries the stacked SSM state, conv windows and KV
-  and writes each layer's slice in place equals, bit for bit, the same
-  scans with the state scanned as inputs and restacked from the outputs.
+  the reference's full-forward logits at every position.
+
+``tests/test_models_smoke.py`` holds the decode scan to a per-layer loop,
+bit for bit.
 """
 import jax
 import jax.numpy as jnp
@@ -21,9 +21,7 @@ from chipbench.refs import granite_hybrid as ref
 from chipbench.tests import tiny_hybrid
 from repro.configs import get_config
 from repro.configs.base import ArchConfig, SSMConfig
-from repro.models import blocks
 from repro.models import model as M
-from repro.models.layers import embed, rmsnorm, unembed
 
 SEED = 11
 #: float32 program against the float32 reference, all on the CPU: what is
@@ -142,57 +140,6 @@ def test_a_mamba2_slot_reused_from_position_0_carries_no_state():
     np.testing.assert_array_equal(reused, fresh)
 
 
-def _scanned_decode(params, cfg, tokens, cache, cache_index):
-    """``decode_step`` in the xs/ys form the SSM models' decode keeps: the
-    same period-by-period scans, each run's state, conv windows or K/V
-    sliced out of the stack, handed to the run's scan as inputs and
-    restacked from its outputs."""
-    runs, per_kind = M._period_runs(cfg)
-    layers = params["layers"]
-
-    def period(carry, p):
-        h, cache = carry
-        for kind, first, layer0, n in runs:
-            index = p * per_kind[kind] + first + jnp.arange(n)
-            mlp = p * len(cfg.mixer_period) + layer0 + jnp.arange(n)
-
-            def body(hh, xs, kind=kind):
-                i, l, c = xs
-                return blocks.interleaved_layer_decode(
-                    M._layer_params(layers[kind], i),
-                    M._layer_params(layers["mlp"], l), hh, cfg, kind, c,
-                    layer=None, cache_index=cache_index)
-            h, ys = jax.lax.scan(body, h, (index, mlp, jax.tree.map(
-                lambda t: t[index], cache[kind])))
-            cache = {**cache, kind: jax.tree.map(
-                lambda t, y: t.at[index].set(y), cache[kind], ys)}
-        return (h, cache), None
-
-    x = embed(params["embed"], tokens, cfg)
-    periods = cfg.num_layers // len(cfg.mixer_period)
-    (x, cache), _ = jax.lax.scan(period, (x, cache), jnp.arange(periods))
-    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    return unembed(params["embed"], x, cfg), cache
-
-
-def test_the_carried_scan_equals_the_xs_ys_form_bit_for_bit(model):
-    _, cfg, params = model
-    step = jax.jit(lambda p, t, c, i: M.decode_step(p, cfg, t, c, i))
-    scanned = jax.jit(lambda p, t, c, i: _scanned_decode(p, cfg, t, c, i))
-    cache = ref_cache = M.init_cache(cfg, 3, 16)
-    tok = ref_tok = jnp.asarray([[3], [1], [4]], jnp.int32)
-    for i in range(16):
-        logits, cache = step(params, tok, cache, jnp.int32(i))
-        want, ref_cache = scanned(params, ref_tok, ref_cache,
-                                  jnp.int32(i))
-        np.testing.assert_array_equal(logits, want)
-        tok = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1)[:, None]
-        ref_tok = jnp.argmax(want[:, -1, :cfg.vocab_size], -1)[:, None]
-    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
-        np.testing.assert_array_equal(a, b)
-    assert cache["mamba"]["state"].dtype == jnp.float32
-
-
 def test_the_cache_holds_both_kinds_of_state():
     cfg = get_config("granite-4.0-h-micro")
     shapes = jax.eval_shape(lambda: M.init_cache(cfg, 96, 256))
@@ -221,6 +168,8 @@ def test_row_stable_decode_names_the_hybrid():
     dict(mixer_period=("mamba", "mlp")),           # no such mixer
     dict(ssm=None),                                # no SSM to interleave
     dict(mixer_period=(), residual_multiplier=0.5),  # not run elsewhere
+    dict(mixer_period=(), residual_multiplier=1.0,   # no whole number of
+         shared_attention_every=6),                  # shared-block groups
 ])
 def test_the_pattern_is_checked_when_configured(bad):
     kw = dict(name="h", family="hybrid", num_layers=20, d_model=64,

@@ -1,6 +1,8 @@
 """Per-arch reduced-config smoke: forward/train-step shapes + finiteness,
 and a one-token decode. (Assignment: every arch gets a smoke test that runs
 one forward/train step on CPU asserting output shapes + no NaNs.)"""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,38 +96,102 @@ def test_row_stable_decode(arch):
     np.testing.assert_array_equal(split, stable)
 
 
+def _reference_layers(cfg):
+    """Every decode layer in order: its kind, its index among the layers
+    whose cache it shares, and its place in the depth."""
+    if cfg.is_interleaved:
+        kinds = cfg.layer_types
+    elif cfg.is_hybrid:
+        groups = cfg.num_layers // cfg.shared_attention_every
+        kinds = (("mamba",) * cfg.shared_attention_every + ("shared",)) \
+            * groups
+    else:
+        kinds = ("mamba" if cfg.is_ssm else "decoder",) * cfg.num_layers
+    seen = {}
+    for depth, kind in enumerate(kinds):
+        seen[kind] = seen.get(kind, -1) + 1
+        yield kind, seen[kind], depth
+
+
 def _reference_decode(params, cfg, tokens, cache, cache_index, row_stable):
-    """``decode_step`` as a Python loop over the layers: each layer's cache
-    is sliced out of the stack, gets its new row, and is put back."""
-    from repro.models import blocks
-    from repro.models.layers import embed, rmsnorm, unembed
+    """``decode_step`` as a Python loop over the layers: each layer gets
+    its cache as a stack of one, at index 0, sliced out of the model's
+    stack, and its new cache is put back."""
+    from repro.models import attention as attn, blocks, ssm
+    from repro.models.layers import embed, mlp, rmsnorm, unembed
     B = tokens.shape[0]
     if row_stable:
         tokens = jnp.pad(tokens, ((0, -B % M.ROW_TILE), (0, 0)))
-    x, kv = embed(params["embed"], tokens, cfg), cache["layers"]
-    for l in range(cfg.num_layers):
-        at = lambda t: t[l]                                 # noqa: E731
-        x, c = blocks.decoder_block_decode(
-            jax.tree.map(at, params["layers"]), x, cfg, jax.tree.map(at, kv),
-            cache_index=cache_index, row_stable=row_stable,
-            cross_cache=jax.tree.map(at, cache["cross"])
-            if cfg.is_encdec else None)
-        kv = jax.tree.map(lambda t, n: t.at[l].set(n), kv, c)
+    x = embed(params["embed"], tokens, cfg)
+    layers, cache = params["layers"], dict(cache)
+    # the kind's parameter stack and the key of its cache stack
+    where = {"decoder": (layers, "layers"), "mamba": (layers, "layers"),
+             "shared": (None, "shared_kv")}
+    if cfg.is_interleaved:
+        where = {k: (layers[k], k) for k in ("mamba", "attention")}
+
+    def residual(x, y):
+        if cfg.residual_multiplier != 1.0:
+            y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+        return x + y
+
+    for kind, i, depth in _reference_layers(cfg):
+        stack, key = where[kind]
+        lp = None if stack is None else jax.tree.map(lambda t: t[i], stack)
+        own = jax.tree.map(lambda t: t[i:i + 1], cache[key])
+        if kind == "decoder":
+            x, own = blocks.decoder_block_decode(
+                lp, x, cfg, own, cache_index=cache_index, layer=0,
+                row_stable=row_stable, cross_cache=jax.tree.map(
+                    lambda t: t[i:i + 1], cache["cross"])
+                if cfg.is_encdec else None)
+        elif kind == "shared":
+            shared = params["shared_attn"]
+            a, own = attn.decode_attn_apply(
+                shared["attn"], rmsnorm(shared["ln1"], x, cfg.norm_eps),
+                cfg, own, cache_index=cache_index, layer=0)
+            x = x + a
+            x = x + mlp(shared["mlp"], rmsnorm(shared["ln2"], x,
+                                               cfg.norm_eps), cfg)
+        else:
+            h = rmsnorm(lp["ln"], x, cfg.norm_eps)
+            if kind == "mamba":
+                y, own = ssm.ssm_decode_step(lp["ssm"], h, cfg, own,
+                                             cache_index, layer=0)
+            else:
+                y, own = attn.decode_attn_apply(
+                    lp["attn"], h, cfg, own, cache_index=cache_index,
+                    layer=0, row_stable=True)
+            x = residual(x, y)
+            if cfg.is_interleaved:
+                lm = jax.tree.map(lambda t: t[depth], layers["mlp"])
+                x = residual(x, mlp(lm["mlp"], rmsnorm(lm["ln"], x,
+                                                       cfg.norm_eps), cfg))
+        cache[key] = jax.tree.map(lambda t, n: t.at[i:i + 1].set(n),
+                                  cache[key], own)
     x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    return unembed(params["embed"], x, cfg)[:B], {**cache, "layers": kv}
+    return unembed(params["embed"], x, cfg)[:B], cache
 
 
 @pytest.mark.parametrize("arch,row_stable", [
     ("granite-3-2b", True), ("granite-3-2b", False),
     ("mixtral-8x7b", False),              # SWA (window 16) and MoE
     ("qwen3-moe-235b-a22b", False),
-    ("seamless-m4t-medium", False), ("seamless-m4t-medium", True)])
+    ("seamless-m4t-medium", False), ("seamless-m4t-medium", True),
+    ("mamba2-370m", False), ("zamba2-2.7b", False),
+    ("granite-4.0-h-micro", False)])
 def test_decode_step_matches_per_layer_reference(arch, row_stable):
-    """The layer scan that carries the stacked cache and writes one row per
-    layer in place gives the per-layer reference's logits, tokens and cache
-    bit for bit: 32 steps fill a 32-deep cache, or wrap mixtral's 16-slot
-    rolling buffer twice."""
+    """The layer scan that carries the stacked caches and updates each
+    layer's slice in place gives the per-layer reference's logits, tokens
+    and cache bit for bit: 32 steps fill a 32-deep cache, or wrap
+    mixtral's 16-slot rolling buffer twice. zamba2 runs two groups of
+    Mamba layers, each closed by the shared block. granite-4.0-h runs its
+    smoke period, one Mamba and one attention layer; its published period
+    is held by the next test."""
     cfg = get_config(arch + "-smoke")
+    if cfg.is_hybrid:
+        cfg = dataclasses.replace(
+            cfg, num_layers=2 * cfg.shared_attention_every)
     params = init_state(cfg, OPT, 0).params
     cache = M.init_cache(cfg, 3, 32, enc_len=16)
     assert cfg.attention != "swa" or cache["layers"]["k"].shape[2] == 16
@@ -146,14 +212,46 @@ def test_decode_step_matches_per_layer_reference(arch, row_stable):
         np.testing.assert_array_equal(a, b)
 
 
+def test_decode_step_matches_per_layer_reference_at_the_published_period():
+    """granite-4.0-h's decode scan at its published period, two periods of
+    5 Mamba, 1 attention and 4 Mamba layers (the small size of
+    ``test_interleaved_hybrid.py``): runs of several layers, the second
+    Mamba run starting at index 5. Over 32 steps, the same tokens fed to
+    both, it gives the per-layer reference's tokens, and its logits and
+    every cache leaf within 1e-6. Not bit for bit: the CPU compiles a
+    layer inside a run's scan a float32 rounding away from the same layer
+    unrolled (logits 3.7e-8 apart, the cache 2.7e-7)."""
+    from chipbench import weights as W
+    from chipbench.kinds import common
+    from chipbench.refs import granite_hybrid
+    from chipbench.tests import tiny_hybrid
+    c = tiny_hybrid.cell(tiny_hybrid.HYBRID).config
+    cfg = common.program_config(c, granite_hybrid)
+    assert cfg.mixer_period == get_config("granite-4.0-h-micro").mixer_period
+    params = W.make(granite_hybrid.layout(c), 11, jnp.float32)
+    step = jax.jit(lambda p, t, c, i: M.decode_step(p, cfg, t, c, i))
+    ref = jax.jit(lambda p, t, c, i: _reference_decode(p, cfg, t, c, i,
+                                                       False))
+    cache = ref_cache = M.init_cache(cfg, 3, 32)
+    tok = jnp.asarray([3, 1, 4], jnp.int32)[:, None]
+    for i in range(32):
+        logits, cache = step(params, tok, cache, jnp.int32(i))
+        ref_logits, ref_cache = ref(params, tok, ref_cache, jnp.int32(i))
+        np.testing.assert_allclose(logits, ref_logits, atol=1e-6, rtol=0)
+        tok = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1)[:, None]
+        np.testing.assert_array_equal(
+            tok, jnp.argmax(ref_logits[:, -1, :cfg.vocab_size], -1)[:, None])
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0)
+
+
 @pytest.mark.parametrize("arch", ["mamba2-370m", "zamba2-2.7b",
                                   "granite-4.0-h-micro"])
 def test_ssm_decode_step_matches_the_xla_state_update(arch, monkeypatch):
     """The decode step through the one-pass state kernel gives the logits
     and cache of the same step with the state updated and read out by XLA
-    ops (``kernels/ref.py``), within float32 rounding, over 12 steps:
-    per-layer leaves (mamba2, zamba2's Mamba layers) and the interleaved
-    hybrid's stack."""
+    ops (``kernels/ref.py``), within float32 rounding, over 12 steps, the
+    state updated in its stack at a traced layer in every model."""
     from repro.kernels import ops, ref
     cfg = get_config(arch + "-smoke")
     params = init_state(cfg, OPT, 0).params

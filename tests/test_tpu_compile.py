@@ -143,27 +143,60 @@ def test_decode_step_writes_the_cache_in_place(one_chip):
             assert update == "bf16[1,256,1,8,64]", update
 
 
-def test_hybrid_decode_step_carries_its_state_in_place(one_chip,
-                                                        monkeypatch):
-    """granite-4.0-h-micro's step at batch 96 and cache 256, the cache
-    donated: the (36, 96, 64, 64, 128) float32 SSM state, the conv windows
-    and the (4, 96, 256, 8, 64) K and V are carried through the layer scan
-    and each layer's slice is updated where it lies. No state-sized copy or
-    dynamic-slice is left, each Mamba layer's state is read by one
-    operation (the kernel that updates it in place and reads it out), the
-    temporaries are under 0.1 GiB, the output aliases the donated cache,
-    and the whole step fits one chip's 15.75 GiB."""
+def _shape(leaf, whole):
+    """A regex of the HLO shape of a stacked cache leaf (``whole``) or of
+    any buffer that ends in one layer's slice of it."""
+    dims = ",".join(map(str, leaf.shape[1:]))
+    dtype = {"float32": "f32", "bfloat16": "bf16"}[str(leaf.dtype)]
+    lead = f"{leaf.shape[0]}," if whole else r"(?:\d+,)*"
+    return rf"{dtype}\[{lead}{dims}\]"
+
+
+def _bytes(shape):
+    dtype, dims = re.fullmatch(r"(\w+)\[([\d,]*)\]", shape).groups()
+    n = {"f32": 4, "bf16": 2}[dtype]
+    for d in dims.split(","):
+        n *= int(d)
+    return n
+
+
+@pytest.mark.parametrize("arch,rows,runs,layout_copies", [
+    ("granite-4.0-h-micro", 96, 2, 0),   # 5 Mamba, 1 attention, 4 Mamba
+    ("zamba2-2.7b", 8, 1, 7),            # 6 Mamba, the shared block
+    ("mamba2-370m", 8, 1, 0)])
+def test_hybrid_decode_step_carries_its_state_in_place(
+        one_chip, monkeypatch, arch, rows, runs, layout_copies):
+    """A decode step of a model with Mamba layers, at its published widths
+    and depth with ``rows`` sequences and cache 256, the cache donated
+    (granite-4.0-h-micro's is its chip cell's: a (36, 96, 64, 64, 128)
+    float32 state and (4, 96, 256, 8, 64) K and V). The SSM state, the conv
+    windows and any K and V are carried through the layer scans and each
+    layer's slice is updated where it lies: in the scans no state-sized or
+    KV-sized copy or dynamic-slice is left, nor a buffer of one layer's
+    state or KV; each Mamba layer's state is read by one operation (the
+    kernel that updates it in place and reads it out); the output aliases
+    the donated cache, and the whole step fits one chip's 15.75 GiB.
+
+    Outside the scans every such buffer is a whole stack, none a copy,
+    and the temporaries are under 0.1 GiB, but for ``layout_copies``:
+    zamba2's state lies on the chip with its 80 heads in the lanes and its
+    K and V with the 256 positions there, while its kernel takes the state
+    row-major (N=64 in the lanes) and its batch read's dynamic-slice and
+    row write take K and V with the head dim (80) in the lanes, so the
+    step converts each whole stack on the way in and out."""
     from repro.configs import get_config
+    from repro.models import model as M
     # the model asks the backend, here the CPU, whether to interpret its
     # kernel: the described chip compiles it
     monkeypatch.setattr(ops, "interpret_here", lambda: False)
-    cfg = get_config("granite-4.0-h-micro")
-    compiled = _serve_step(cfg, one_chip, 96, 256, row_stable=False,
+    cfg = get_config(arch)
+    compiled = _serve_step(cfg, one_chip, rows, 256, row_stable=False,
                            donate_argnums=(1,))
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 0.1 * 2**30
-    state_bytes = 36 * 96 * 64 * 64 * 128 * 4
-    assert mem.alias_size_in_bytes >= state_bytes
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, rows, 256))
+    stacks = [c["state"] for c in cache.values() if "state" in c] + \
+        [c["k"] for c in cache.values() if "k" in c]
+    assert mem.alias_size_in_bytes >= stacks[0].size * 4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 15.75 * 2**30
 
@@ -171,29 +204,40 @@ def test_hybrid_decode_step_carries_its_state_in_place(one_chip,
     # one, a dynamic-slice is a read fused into its consumer)
     text = compiled.as_text()
     fused = set(re.findall(r"kind=k\w+, calls=(%[\w.-]+)", text))
-    bodies = re.split(r"\n(?=\S)", text)
-    buffers = "\n".join(b for b in bodies
-                        if b.split(" ", 1)[0] not in fused)
-    big = re.findall(r"= ((?:f32\[(?:36,|1,)?96,64,64,128\]|"
-                     r"bf16\[(?:4,|1,)?96,256,8,64\]))\S* ([\w-]+)\(",
-                     buffers)
-    assert big
-    for shape, op in big:
+    bodies = [b for b in re.split(r"\n(?=\S)", text)
+              if b.split(" ", 1)[0] not in fused]
+    sized = "|".join(_shape(t, whole=False) for t in stacks)
+    whole = re.compile("|".join(_shape(t, whole=True) for t in stacks))
+
+    def buffers(entry):
+        return re.findall(rf"= ((?:{sized}))\S* ([\w-]+)\(", "\n".join(
+            b for b in bodies if b.startswith("ENTRY") == entry))
+
+    assert buffers(entry=False)
+    for shape, op in buffers(entry=False):
         assert op not in ("copy", "dynamic-slice"), (shape, op)
-        assert shape.startswith(("f32[36,", "bf16[4,")), (shape, op)
+        assert whole.fullmatch(shape), (shape, op)
+    outside = buffers(entry=True)
+    assert all(whole.fullmatch(shape) for shape, _ in outside), outside
+    copies = [shape for shape, op in outside
+              if op.startswith("copy") or op == "dynamic-slice"]
+    assert len(copies) == layout_copies, copies
+    assert mem.temp_size_in_bytes < 0.1 * 2**30 + sum(map(_bytes, copies))
 
     # the operations that take a state stack as an operand, computation by
-    # computation: one in each Mamba run's scan body, the kernel
+    # computation, past the layout copies counted above: one in each Mamba
+    # run's scan body, the kernel
     shapes = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))
-    state = re.compile(r"f32\[(?:36,|1,)?96,64,64,128\]")
+    state = re.compile(_shape(stacks[0], whole=True))
     moves = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
     readers = []
-    for body in bodies:
+    for body in re.split(r"\n(?=\S)", text):
+        skip = moves + (("copy",) if body.startswith("ENTRY") else ())
         ops_here = [op for op, operands in re.findall(
             r"\n\s*(?:ROOT )?%[\w.-]+ = .*? ([\w-]+)\(([^)]*)\)", body)
-            if op not in moves and any(
+            if op not in skip and any(
                 state.fullmatch(shapes.get(o, ""))
                 for o in re.findall(r"%[\w.-]+", operands))]
         if ops_here:
             readers.append(ops_here)
-    assert readers == [["custom-call"]] * 2, readers
+    assert readers == [["custom-call"]] * runs, readers
