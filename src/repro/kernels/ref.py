@@ -74,6 +74,13 @@ def ssm_state_update_reference(state, layer, fresh, a, xdt, bm, cm):
     return state, y
 
 
+def kv_row_write_reference(k, v, k_new, v_new, layer, slot):
+    """The rows k_new, v_new (B, Hkv, hd) written into the stacks k, v
+    (L, B, S, Hkv, hd) at (layer, :, slot)."""
+    return (k.at[layer, :, slot].set(k_new.astype(k.dtype)),
+            v.at[layer, :, slot].set(v_new.astype(v.dtype)))
+
+
 def repack_reference(src, idx):
     """out[i] = src[idx[i]]."""
     return src[idx]

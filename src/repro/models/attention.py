@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops
 from repro.models.params import ParamDef
 from repro.models.layers import apply_rope, rmsnorm
 
@@ -310,10 +311,12 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     (``decode_step(row_stable=True)``); they stay out of the cache.
 
     The new token's K and V rows are written into the stack in place at
-    (``layer``, :, slot), one ``dynamic_update_slice`` each, attention
-    reads layer ``layer``'s rows straight from the stack, and the stack
-    comes back with only those rows changed. A ``cross`` cache is only
-    read.
+    (``layer``, :, slot), attention reads layer ``layer``'s rows straight
+    from the stack, and the stack comes back with only those rows changed.
+    ``kernels.ops.kv_row_write`` writes them: one kernel into the tile
+    column that holds the slot where the chip keeps the positions in the
+    lanes, else one ``dynamic_update_slice`` each. A ``cross`` cache is
+    only read.
 
     ``row_stable`` reads the cache one sequence at a time, so that a
     sequence's scores do not depend on how many others the program holds.
@@ -333,12 +336,9 @@ def decode_attn_apply(params, x, cfg: ArchConfig, cache, *, cache_index,
     else:
         S = cache["k"].shape[2]
         slot = jnp.mod(cache_index, S) if cfg.attention == "swa" else cache_index
-        k_new, v_new, at = k_new[None], v_new[None], (layer, 0, slot, 0, 0)
         with jax.named_scope("kv_write"):
-            k = jax.lax.dynamic_update_slice(
-                cache["k"], k_new.astype(cache["k"].dtype), at)
-            v = jax.lax.dynamic_update_slice(
-                cache["v"], v_new.astype(cache["v"].dtype), at)
+            k, v = ops.kv_row_write(cache["k"], cache["v"], k_new[:, 0],
+                                    v_new[:, 0], layer, slot)
         cache = {"k": k, "v": v}
         with jax.named_scope("attend"):        # the mask is attention's
             kpos = jnp.arange(S)

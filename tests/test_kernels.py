@@ -179,3 +179,79 @@ def test_ssm_state_update(L, B, H, P, N, fresh):
 def test_ssm_state_block_heads(heads, p, n, want):
     from repro.kernels.ssm_state import block_heads
     assert block_heads(heads, p, n) == want
+
+
+@pytest.mark.parametrize("slot", [0, 127, 128, 255])
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("batch", [16, 96], ids=["two_blocks", "hybrid_96"])
+def test_kv_row_write(batch, layer, slot):
+    """The one-position K/V row write into a (3, B, 256, 8, 64) bf16 stack,
+    at both ends of both 128-position tile columns, equals ``.at[layer, :,
+    slot].set`` bit for bit: every other layer, position and column keeps
+    its bytes. 16 sequences are two blocks of 8; 96 the hybrid cell's
+    batch, twelve."""
+    shape = (3, batch, 256, 8, 64)
+    k, v = _rand(shape, jnp.bfloat16), _rand(shape, jnp.bfloat16)
+    k_new = _rand((batch, 8, 64), jnp.float32)      # cast by the kernel
+    v_new = _rand((batch, 8, 64), jnp.bfloat16)
+    got = ops.kv_row_write(k, v, k_new, v_new, jnp.int32(layer),
+                           jnp.int32(slot), interpret=True)
+    want = ref.kv_row_write_reference(k, v, k_new, v_new, layer, slot)
+    for g, w, old in zip(got, want, (k, v)):
+        assert g.dtype == jnp.bfloat16
+        g, w, old = (np.asarray(a).view(np.uint16) for a in (g, w, old))
+        np.testing.assert_array_equal(g, w)
+        changed = np.ones(shape, bool)
+        changed[layer, :, slot] = False
+        np.testing.assert_array_equal(g[changed], old[changed])
+
+
+@pytest.mark.parametrize("shape,sharded,kernel", [
+    ((2, 8, 256, 2, 64), False, True),     # positions in the lanes
+    ((2, 8, 256, 2, 64), True, False),     # a step GSPMD partitions
+    ((2, 8, 256, 2, 128), False, False),   # the head dim fills the lanes
+    ((2, 8, 32, 2, 64), False, False),     # S fills no lane tile
+], ids=["lane_major", "sharding_context", "hd_128", "s_32"])
+def test_kv_row_write_takes_the_kernel_where_it_fits(shape, sharded, kernel):
+    """``ops.kv_row_write`` takes the kernel for a stack the chip keeps with
+    the positions in the lanes, on one device's rows; under an active
+    sharding context, or for any other stack, XLA's dynamic-update-slice
+    writes the rows. Both give the reference's bytes."""
+    import contextlib
+    from jax.sharding import Mesh
+    from repro.parallel.context import sharding_context
+    k, v = _rand(shape, jnp.bfloat16), _rand(shape, jnp.bfloat16)
+    rows = (shape[1],) + shape[3:]
+    k_new, v_new = _rand(rows, jnp.bfloat16), _rand(rows, jnp.bfloat16)
+    slot = shape[2] - 1
+    context = sharding_context(Mesh(np.array(jax.devices()[:1]), ("data",)),
+                               {}) if sharded else contextlib.nullcontext()
+    with context:
+        # a fresh function: make_jaxpr would reuse another case's trace
+        jaxpr = str(jax.make_jaxpr(lambda *a: ops.kv_row_write(*a))(
+            k, v, k_new, v_new, jnp.int32(1), jnp.int32(slot)))
+        got = ops.kv_row_write(k, v, k_new, v_new, jnp.int32(1),
+                               jnp.int32(slot))
+    assert ("kv_row_write" in jaxpr) == kernel
+    want = ref.kv_row_write_reference(k, v, k_new, v_new, 1, slot)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint16),
+                                      np.asarray(w).view(np.uint16))
+
+
+@pytest.mark.parametrize("shape,itemsize,lane_major,bb", [
+    ((40, 256, 256, 8, 64), 2, True, 8),    # granite-3-2b: 1 MiB blocks
+    ((4, 96, 256, 8, 64), 2, True, 8),      # granite-4.0-h-micro
+    ((9, 8, 256, 32, 80), 2, True, 1),      # zamba2's shared block
+    ((3, 8, 256, 2, 16), 4, True, 8),       # a small model, float32
+    ((32, 8, 256, 8, 128), 2, False, None),  # phi4-mini: hd fills lanes
+    ((40, 256, 32, 8, 64), 2, False, None),  # S fills no lane tile
+])
+def test_kv_row_lane_major_and_block_seqs(shape, itemsize, lane_major, bb):
+    """The kernel is taken where the chip keeps the positions in the lanes,
+    with the most sequences a block of at most 1 MiB holds."""
+    from repro.kernels import kv_row
+    assert kv_row.lane_major(shape) == lane_major
+    if lane_major:
+        _, batch, _, heads, hd = shape
+        assert kv_row.block_seqs(batch, heads, hd, itemsize) == bb

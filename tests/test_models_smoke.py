@@ -279,6 +279,59 @@ def test_ssm_decode_step_matches_the_xla_state_update(arch, monkeypatch):
                                    atol=1e-5, rtol=1e-5)
 
 
+#: decode positions that write both ends of both 128-position tile columns
+#: of a 256-deep cache
+KV_ROW_POSITIONS = (0, 1, 127, 128, 129, 255)
+
+
+@pytest.mark.parametrize("model,rows", [
+    ("dense", 8), ("dense", 4), ("dense", 2), ("hybrid_period", 8)])
+def test_decode_kv_row_kernel_matches_the_xla_write(model, rows, monkeypatch):
+    """At cache 256 the K/V rows are written by the ``kv_row_write`` kernel
+    (interpreted here); the same steps with XLA's dynamic-update-slice
+    write give the same logits, tokens and cache leaves bit for bit. The
+    dense decoder runs the row-stable step over 8, 4 and 2 rows of a batch
+    of 8 (a replica on 1, 2 or 4 chips); the interleaved hybrid its
+    published period, two periods of 5 Mamba, 1 attention and 4 Mamba
+    layers at the small size of ``test_interleaved_hybrid.py``."""
+    from repro.kernels import ops
+    if model == "dense":
+        cfg = get_config("granite-3-2b-smoke")
+        params = init_state(cfg, OPT, 0).params
+    else:
+        from chipbench import weights as W
+        from chipbench.kinds import common
+        from chipbench.refs import granite_hybrid
+        from chipbench.tests import tiny_hybrid
+        c = tiny_hybrid.cell(tiny_hybrid.HYBRID).config
+        cfg = common.program_config(c, granite_hybrid)
+        params = W.make(granite_hybrid.layout(c), 11, jnp.float32)
+    row_stable = model == "dense"
+    tokens = jnp.arange(3, 3 + 8, dtype=jnp.int32)[:rows, None]
+
+    def run():
+        def one(p, t, c, i):
+            return M.decode_step(p, cfg, t, c, i, row_stable=row_stable)
+        cache = M.init_cache(cfg, rows, 256)
+        uses_kernel = "kv_row_write" in str(
+            jax.make_jaxpr(one)(params, tokens, cache, jnp.int32(0)))
+        step, tok, out = jax.jit(one), tokens, []
+        for i in KV_ROW_POSITIONS:
+            logits, cache = step(params, tok, cache, jnp.int32(i))
+            tok = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1)[:, None]
+            out += [np.asarray(logits), np.asarray(tok)]
+        return uses_kernel, out, cache
+
+    kernel, got, cache = run()
+    monkeypatch.setattr(ops, "lane_major", lambda shape: False)
+    xla, want, want_cache = run()
+    assert kernel and not xla
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_row_stable_decode_refuses_shared_state():
     cfg = get_config("mamba2-370m-smoke")
     assert not M.row_stable_decode(cfg)

@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and pytest-xdist workers each import every test file.
 """
+import functools
 import os
 import re
 
@@ -114,33 +115,93 @@ def test_row_stable_decode_runs_the_same_matmuls(one_chip):
     assert shapes[8] and shapes[4] == shapes[8] and shapes[2] == shapes[8]
 
 
-def test_decode_step_writes_the_cache_in_place(one_chip):
-    """granite-3-2b's row-stable step at batch 256 and cache 256, the cache
-    donated: the stacked (40, 256, 256, 8, 64) K and V caches are carried
-    through the layer scan and only the new row of each layer is written.
-    No whole-cache or layer-sized cache copy or slice is left, the
-    temporaries are far below one cache (2.5 GiB each), and the output
-    aliases the donated cache."""
-    from repro.configs import get_config
-    cfg = get_config("granite-3-2b")
-    compiled = _serve_step(cfg, one_chip, 256, 256, donate_argnums=(1,))
+def test_kv_row_write_compiles(one_chip):
+    """granite-3-2b's stacked (40, 256, 256, 8, 64) bf16 K and V at batch
+    256, one layer's rows written at a traced layer and slot."""
+    stack, rows = (40, 256, 256, 8, 64), (256, 8, 64)
+    write = jax.jit(functools.partial(ops.kv_row_write, interpret=False))
+    _assert_kernel(write.lower(
+        _spec(one_chip, stack), _spec(one_chip, stack),
+        _spec(one_chip, rows), _spec(one_chip, rows),
+        _spec(one_chip, (), jnp.int32), _spec(one_chip, (), jnp.int32)))
+
+
+def _assert_rows_written_in_place(compiled, rows, cache_len):
+    """granite-3-2b's stacked (40, rows, cache_len, 8, 64) K and V, donated,
+    lie on the chip with the positions in the lanes and are carried through
+    the layer scan; the ``kv_row_write`` kernel takes them as bitcasts,
+    viewed (40, rows, 8, 64, cache_len), and writes each layer's new rows
+    into the tile column that holds the position. Every cache- or
+    layer-sized buffer, whatever its leading dim, is a parameter, a tuple
+    element or a bitcast, and the kernel's output the one pair of stacks
+    written: no such copy, slice or dynamic-update-slice is left, nor a row
+    padded to 128 lanes; the temporaries are far below one cache, and the
+    output aliases the donated cache."""
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 256 * 2**20
-    cache_bytes = 2 * 40 * 256 * 256 * 8 * 64 * 2
+    cache_bytes = 2 * 40 * rows * cache_len * 8 * 64 * 2
     assert mem.alias_size_in_bytes >= cache_bytes
 
     text = compiled.as_text()
-    shapes = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))
-    cache_sized = re.findall(
-        r"= (bf16\[(?:\d+,)?256,256,8,64\])\S* ([\w-]+)\(([^)]*)\)", text)
+    sized = (rf"bf16\[(?:\d+,)?(?:{rows},{cache_len},8,64|"
+             rf"{rows},8,64,{cache_len})\]")
+    cache_sized = re.findall(rf"= ({sized})\S* ([\w-]+)\(", text)
     assert cache_sized
-    for shape, op, operands in cache_sized:
-        assert op in ("parameter", "get-tuple-element",
-                      "dynamic-update-slice"), (shape, op)
-        if op == "dynamic-update-slice":
-            update = shapes[operands.split(", ")[1]]
-            assert shape == "bf16[40,256,256,8,64]"
-            assert update == "bf16[1,256,1,8,64]", update
+    for shape, op in cache_sized:
+        assert op in ("parameter", "get-tuple-element", "bitcast"), (shape, op)
+    writers = re.findall(
+        rf"%([\w-]+?)(?:\.\d+)? = \({sized}\S*, {sized}\S*\) ([\w-]+)\(",
+        text)
+    assert writers == [("kv_row_write", "custom-call")], writers
+    assert f"bf16[1,{rows},1,8,64]" not in text
+
+
+def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch):
+    """granite-3-2b's row-stable step at batch 256 and cache 256 (its chip
+    cell's), the cache donated, writes each layer's rows in place
+    (``_assert_rows_written_in_place``): no cache- or layer-sized copy or
+    slice, no row padded to bf16[1,256,1,8,64], temporaries under 256 MiB
+    against 2.5 GiB a cache."""
+    from repro.configs import get_config
+    # the model asks the backend, here the CPU, whether to interpret its
+    # kernels: the described chip compiles them
+    monkeypatch.setattr(ops, "interpret_here", lambda: False)
+    cfg = get_config("granite-3-2b")
+    compiled = _serve_step(cfg, one_chip, 256, 256, donate_argnums=(1,))
+    _assert_rows_written_in_place(compiled, 256, 256)
+
+
+def test_long_cache_decode_step_writes_the_cache_in_place(one_chip,
+                                                         monkeypatch):
+    """At decode_32k's depth, a 32768-deep cache, granite-3-2b's stacks at
+    batch 2 also lie with the positions in the lanes: the row-stable step
+    writes each layer's rows with the kernel, in place, and converts no
+    stack on the way in or out."""
+    from repro.configs import get_config
+    monkeypatch.setattr(ops, "interpret_here", lambda: False)
+    cfg = get_config("granite-3-2b")
+    compiled = _serve_step(cfg, one_chip, 2, 32768, donate_argnums=(1,))
+    _assert_rows_written_in_place(compiled, 2, 32768)
+
+
+def test_head_dim_128_decode_step_keeps_the_xla_write(one_chip, monkeypatch):
+    """phi4-mini's head dim (128) fills the lanes, so its (32, 8, 256, 8,
+    128) K and V stacks enter row-major, a position whole (8, 128) tiles:
+    the step at batch 8 and cache 256 writes them with XLA's
+    dynamic-update-slice, takes no ``kv_row_write``, and its temporaries
+    are the XLA write's (268,823,040 bytes)."""
+    from repro.configs import get_config
+    monkeypatch.setattr(ops, "interpret_here", lambda: False)
+    cfg = get_config("phi4-mini-3.8b")
+    compiled = _serve_step(cfg, one_chip, 8, 256, donate_argnums=(1,))
+    text = compiled.as_text()
+    assert not re.search(r"%kv_row_write\S* = ", text)
+    entry = text[text.index("\nENTRY"):]
+    layouts = re.findall(r"= bf16\[32,8,256,8,128\](\{[^}]*\}) parameter\(",
+                         entry)
+    assert layouts and set(layouts) == {"{4,3,2,1,0:T(8,128)(2,1)}"}, layouts
+    assert "dynamic-update-slice(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 268_823_040
 
 
 def _shape(leaf, whole):
@@ -162,7 +223,7 @@ def _bytes(shape):
 
 @pytest.mark.parametrize("arch,rows,runs,layout_copies", [
     ("granite-4.0-h-micro", 96, 2, 0),   # 5 Mamba, 1 attention, 4 Mamba
-    ("zamba2-2.7b", 8, 1, 7),            # 6 Mamba, the shared block
+    ("zamba2-2.7b", 8, 1, 2),            # 6 Mamba, the shared block
     ("mamba2-370m", 8, 1, 0)])
 def test_hybrid_decode_step_carries_its_state_in_place(
         one_chip, monkeypatch, arch, rows, runs, layout_copies):
@@ -179,11 +240,11 @@ def test_hybrid_decode_step_carries_its_state_in_place(
 
     Outside the scans every such buffer is a whole stack, none a copy,
     and the temporaries are under 0.1 GiB, but for ``layout_copies``:
-    zamba2's state lies on the chip with its 80 heads in the lanes and its
-    K and V with the 256 positions there, while its kernel takes the state
-    row-major (N=64 in the lanes) and its batch read's dynamic-slice and
-    row write take K and V with the head dim (80) in the lanes, so the
-    step converts each whole stack on the way in and out."""
+    zamba2's state lies on the chip with its 80 heads in the lanes, while
+    its kernel takes the state row-major (N=64 in the lanes), so the step
+    converts the whole state stack on the way in and out. Its K and V lie
+    with the 256 positions in the lanes, where the batch read and the row
+    write (``kv_row_write``) take them."""
     from repro.configs import get_config
     from repro.models import model as M
     # the model asks the backend, here the CPU, whether to interpret its
